@@ -1,13 +1,2 @@
 """repro — jax_pallas reproduction of "Breaking the Memory Wall for AI
-Chip with a New Dimension" grown into a serving/training system.
-
-Partitionable threefry is the default on jax >= 0.5; on 0.4.x it must be
-opted into, otherwise RNG draws depend on the sharding of the consuming
-computation and sharded init != single-device init.
-"""
-import jax as _jax
-
-try:
-    _jax.config.update("jax_threefry_partitionable", True)
-except Exception:  # unknown flag on some versions: already the default
-    pass
+Chip with a New Dimension" grown into a serving/training system."""

@@ -26,17 +26,8 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 from repro.kernels.decode_attention.kernel import combine_splits
-
-
-def axis_size(axis: str) -> int:
-    """Static size of a bound mesh axis (jax.lax.axis_size is >= 0.5)."""
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(axis)
-    frame = jax.core.axis_frame(axis)       # 0.4.x: int or frame object
-    return frame if isinstance(frame, int) else frame.size
 
 
 # ------------------------------------------------- near-memory LSE merge
@@ -84,7 +75,7 @@ def ring_allreduce_int8(x, axis: str):
     Each hop passes the ORIGINAL quantized block along the ring and
     accumulates the dequantized value — n-1 hops, int8 bytes on the wire.
     """
-    n = axis_size(axis)
+    n = jax.lax.axis_size(axis)
     q, scale = quantize_int8(x)
     acc = dequantize_int8(q, scale)
     perm = [(i, (i + 1) % n) for i in range(n)]
@@ -106,21 +97,18 @@ def compressed_tree_psum(grads, mesh, axis: str = "pod", error_feedback=None):
         error_feedback = jax.tree.map(
             lambda g: jnp.zeros(g.shape, jnp.float32), grads)
 
-    other_axes = tuple(a for a in mesh.axis_names if a != axis)
-
     def one(g, err):
         def body(gl, el):
             x = gl.astype(jnp.float32) + el
             q, scale = quantize_int8(x)
-            reduced = ring_allreduce_int8(x, axis) / axis_size(axis)
+            reduced = ring_allreduce_int8(x, axis) / jax.lax.axis_size(axis)
             new_err = x - dequantize_int8(q, scale)
             return reduced.astype(gl.dtype), new_err
 
-        fn = shard_map(
+        fn = jax.shard_map(
             body, mesh=mesh,
             in_specs=(P(), P()), out_specs=(P(), P()),
-            check_rep=False,
-            auto=frozenset(other_axes),
+            axis_names=frozenset({axis}), check_vma=False,
         )
         return fn(g, err)
 

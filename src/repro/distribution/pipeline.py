@@ -25,7 +25,6 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 
 def stage_params_split(params_stacked, num_stages: int):
@@ -94,9 +93,9 @@ def pipelined_forward(layer_fn, stage_params, x_microbatches, mesh: Mesh,
         return jax.lax.psum(outs, stage_axis)
 
     spec_p = jax.tree.map(lambda _: P(stage_axis), stage_params)
-    fn = shard_map(
+    fn = jax.shard_map(
         stage_fn, mesh=mesh,
         in_specs=(spec_p, P()), out_specs=P(),
-        check_rep=False,
+        check_vma=False,
     )
     return fn(stage_params, x_microbatches)

@@ -8,3 +8,17 @@
 #   paged_attention/  flash-decoding through a UniMem block table (paged KV)
 #   ssd_scan/         Mamba-2 SSD intra-chunk dual form
 #   grouped_matmul/   per-expert MoE matmul (vector-unit sparsity)
+#   paged_prefill/    chunk prefill through the same block table
+#
+# Every ops.py runs its kernel in the Pallas interpreter on the CPU
+# backend only, and compiled everywhere else (`interpret_default`).
+
+import jax
+
+
+def interpret_default(interpret: bool | None) -> bool:
+    """An explicit `interpret` wins; None means the interpreter on the
+    CPU backend and the compiled kernel on any other."""
+    if interpret is None:
+        return jax.default_backend() == "cpu"
+    return interpret

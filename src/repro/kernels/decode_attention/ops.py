@@ -5,11 +5,8 @@ from functools import partial
 
 import jax
 
+from repro.kernels import interpret_default
 from repro.kernels.decode_attention import kernel as K
-
-
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
 
 
 @partial(jax.jit, static_argnames=("kv_splits", "interpret"))
@@ -17,7 +14,7 @@ def decode_attention(q, k_cache, v_cache, positions, kv_splits=8,
                      interpret=None):
     """q: (b, hq, d); caches (b, S, hkv, d); positions (b,) inclusive
     newest index.  Returns (b, hq, d)."""
-    interpret = (not _on_tpu()) if interpret is None else interpret
+    interpret = interpret_default(interpret)
     b, hq, d = q.shape
     m, l, acc = K.decode_attention_pallas(
         q, k_cache, v_cache, positions, kv_splits=kv_splits,

@@ -5,18 +5,15 @@ from functools import partial
 
 import jax
 
+from repro.kernels import interpret_default
 from repro.kernels.flash_attention import kernel as K
-
-
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
 
 
 @partial(jax.jit, static_argnames=("causal", "block_q", "block_kv",
                                    "q_offset", "interpret"))
 def flash_attention(q, k, v, causal=True, block_q=K.DEF_BQ, block_kv=K.DEF_BKV,
                     q_offset=0, interpret=None):
-    interpret = (not _on_tpu()) if interpret is None else interpret
+    interpret = interpret_default(interpret)
     return K.flash_attention_pallas(q, k, v, causal=causal, block_q=block_q,
                                     block_kv=block_kv, q_offset=q_offset,
                                     interpret=interpret)

@@ -5,16 +5,13 @@ from functools import partial
 
 import jax
 
+from repro.kernels import interpret_default
 from repro.kernels.grouped_matmul import kernel as K
-
-
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
 
 
 @partial(jax.jit, static_argnames=("block_c", "block_f", "block_k", "interpret"))
 def grouped_matmul(x, w, block_c=K.DEF_BC, block_f=K.DEF_BF, block_k=K.DEF_BK,
                    interpret=None):
-    interpret = (not _on_tpu()) if interpret is None else interpret
+    interpret = interpret_default(interpret)
     return K.grouped_matmul_pallas(x, w, block_c=block_c, block_f=block_f,
                                    block_k=block_k, interpret=interpret)

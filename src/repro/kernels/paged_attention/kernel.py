@@ -12,18 +12,22 @@ travels back through HBM.
 
 Kernel geometry
 ---------------
-* **Grid (b, kv_heads, page_blocks)** — the first two dims are
-  `parallel` (the megacore split: Mosaic distributes independent
-  (batch, head) cells across the two TensorCores), the last is
-  `arbitrary`, i.e. SEQUENTIAL: it walks the block table in order while
-  the online-softmax carry persists in VMEM scratch.  This is the fused
-  single-pass form — the old two-pass formulation (per-page f32
-  partials (b, hkv, max_pages, group, hd) written to HBM, then a
-  `combine_pages` merge) no longer exists in the hot path.
+* **Grid (b, page_blocks)** — the batch dim is `parallel`, the page
+  dim is `arbitrary`, i.e. SEQUENTIAL: it walks the block table in
+  order while the online-softmax carry persists in VMEM scratch.  This
+  is the fused single-pass form — no per-page partials are written to
+  HBM and merged afterwards.
+* **Whole-page blocks, heads inside** — each K/V block is one whole
+  arena page, `(1, page, hkv, d)`: every KV head of the page arrives in
+  ONE DMA, so a page's bytes cross HBM once per step, and the block's
+  last two dims equal the array's (the Mosaic tiling rule — a block
+  taking one head out of hkv in the second-minor dim is not a legal
+  tile).  The kernel loops over the heads (static unroll), slicing each
+  head's (page, d) rows out of the VMEM block.
 * **VMEM carry** — running (m, l, acc) live in `scratch_shapes` VMEM
-  (`(g_pad, 1)`, `(g_pad, 1)`, `(g_pad, d_pad)` f32), initialized at
-  page-block 0 and folded log-sum-exp-style each block; the output
-  block is written once, at the LAST page block.
+  (`(hkv, g_pad, 1)`, `(hkv, g_pad, 1)`, `(hkv, g_pad, d_pad)` f32),
+  initialized at page-block 0 and folded log-sum-exp-style each block;
+  the output block is written once, at the LAST page block.
 * **Tiling** — the query group is padded to `g_pad` (8 f32 sublanes)
   and the head dim to `d_pad` (128 lanes), so every VMEM tile the MXU
   sees is (8k, 128k)-aligned.  q is padded host-side (tiny); K/V page
@@ -126,21 +130,24 @@ def reset_carry(m_scr, l_scr, acc_scr):
     acc_scr[...] = jnp.zeros_like(acc_scr)
 
 
-def load_kv_block(kv_refs, ppb: int, d: int, d_pad: int, scale_refs=None):
-    """Concatenate a grid cell's ppb page tiles into one (ppb*page, d_pad)
-    K and V, lane-padding in-register (the arena is never copied).
+def load_kv_block(kv_refs, h: int, ppb: int, d: int, d_pad: int,
+                  scale_refs=None):
+    """Head `h`'s rows of a grid cell's ppb page blocks, concatenated
+    into one (ppb*page, d_pad) K and V, lane-padding in-register (the
+    arena is never copied).
 
     With `scale_refs` (quantized arena: K scales in slots [0, ppb), V
     scales in [ppb, 2*ppb)), the int8/fp8 tiles are dequantized here —
     f32 multiply against the (ppb*page, 1) per-token scale column while
     the tile is already in VMEM, so the dequant costs no HBM traffic."""
-    k = jnp.concatenate([kv_refs[j][0, :, 0, :] for j in range(ppb)], axis=0)
-    v = jnp.concatenate([kv_refs[ppb + j][0, :, 0, :] for j in range(ppb)],
+    k = jnp.concatenate([kv_refs[j][0, :, h, :] for j in range(ppb)], axis=0)
+    v = jnp.concatenate([kv_refs[ppb + j][0, :, h, :] for j in range(ppb)],
                         axis=0)
     if scale_refs is not None:
-        ks = jnp.concatenate([scale_refs[j][0] for j in range(ppb)], axis=0)
-        vs = jnp.concatenate([scale_refs[ppb + j][0] for j in range(ppb)],
-                             axis=0)
+        ks = jnp.concatenate([scale_refs[j][0][:, h:h + 1]
+                              for j in range(ppb)], axis=0)
+        vs = jnp.concatenate([scale_refs[ppb + j][0][:, h:h + 1]
+                              for j in range(ppb)], axis=0)
         k = k.astype(jnp.float32) * ks                 # (ppb*page, d) * (.., 1)
         v = v.astype(jnp.float32) * vs
     if d_pad != d:
@@ -149,35 +156,47 @@ def load_kv_block(kv_refs, ppb: int, d: int, d_pad: int, scale_refs=None):
     return k, v
 
 
-def accumulate_block(s, valid, v, m_scr, l_scr, acc_scr):
-    """Fold one (rows, ppb*page) score block into the (m, l, acc) carry.
-    p is masked explicitly: a fully-invalid block keeps m at NEG_INF,
-    where exp(s - m) would otherwise be exp(0) = 1 per masked entry —
-    so invalid rows/blocks leave the carry at exact zero."""
+def attend_block(q_ref, kv_refs, scale_refs, valid, m_scr, l_scr, acc_scr,
+                 *, hkv: int, ppb: int, d: int, d_pad: int):
+    """One grid cell of the page walk for every KV head: scores of head
+    h's query rows against its K rows of the cell's pages, folded into
+    head h's carry.  `valid` ((rows, ppb*page) bool) is shared by all
+    heads — positions do not depend on the head."""
+    for h in range(hkv):
+        k, v = load_kv_block(kv_refs, h, ppb, d, d_pad, scale_refs)
+        s = jnp.dot(q_ref[0, h], k.T, preferred_element_type=jnp.float32)
+        accumulate_block(s / math.sqrt(d), valid, v, h, m_scr, l_scr, acc_scr)
+
+
+def accumulate_block(s, valid, v, h: int, m_scr, l_scr, acc_scr):
+    """Fold one (rows, ppb*page) score block into head h's (m, l, acc)
+    carry.  p is masked explicitly: a fully-invalid block keeps m at
+    NEG_INF, where exp(s - m) would otherwise be exp(0) = 1 per masked
+    entry — so invalid rows/blocks leave the carry at exact zero."""
     s = jnp.where(valid, s, NEG_INF)
-    m_prev = m_scr[...]
+    m_prev = m_scr[h]
     m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
     p = jnp.exp(s - m_new) * valid.astype(jnp.float32)
     corr = jnp.exp(m_prev - m_new)
-    l_scr[...] = l_scr[...] * corr + p.sum(axis=-1, keepdims=True)
-    m_scr[...] = m_new
-    acc_scr[...] = acc_scr[...] * corr + jnp.dot(
+    l_scr[h] = l_scr[h] * corr + p.sum(axis=-1, keepdims=True)
+    m_scr[h] = m_new
+    acc_scr[h] = acc_scr[h] * corr + jnp.dot(
         p.astype(v.dtype), v, preferred_element_type=jnp.float32)
 
 
 def emit_output(o_ref, l_scr, acc_scr):
     """Normalize the carry into the output block (call at the LAST
     page block); zero-l rows (fully masked) emit exact zeros."""
-    o_ref[0, 0] = (acc_scr[...] /
-                   jnp.maximum(l_scr[...], 1e-30)).astype(o_ref.dtype)
+    o_ref[0] = (acc_scr[...] /
+                jnp.maximum(l_scr[...], 1e-30)).astype(o_ref.dtype)
 
 
 def emit_partials(acc_ref, m_ref, l_ref, m_scr, l_scr, acc_scr):
     """Write the raw carry (call at the LAST page block): the per-shard
     online-softmax summary a later log-sum-exp merge normalizes."""
-    acc_ref[0, 0] = acc_scr[...].astype(acc_ref.dtype)
-    m_ref[0, 0] = m_scr[...]
-    l_ref[0, 0] = l_scr[...]
+    acc_ref[0] = acc_scr[...].astype(acc_ref.dtype)
+    m_ref[0] = m_scr[...]
+    l_ref[0] = l_scr[...]
 
 
 def block_kv_positions(ppos_ref, bi, pi, ppb: int, page: int, rows: int):
@@ -188,31 +207,61 @@ def block_kv_positions(ppos_ref, bi, pi, ppb: int, page: int, rows: int):
         [ppos_ref[bi, pi * ppb + j] + within for j in range(ppb)], axis=1)
 
 
-def kv_block_specs(page: int, d: int, ppb: int):
+def kv_block_specs(page: int, hkv: int, d: int, ppb: int):
     """One K and one V BlockSpec per page slot of a grid cell, indexed
-    through the scalar-prefetched block table (first prefetch ref);
-    their DMAs are independent and pipeline across the sequential walk."""
+    through the scalar-prefetched block table (first prefetch ref).
+    Each block is a whole page, all KV heads included; the DMAs are
+    independent and pipeline across the sequential walk."""
     def spec(j):
         return pl.BlockSpec(
-            (1, page, 1, d),
-            lambda bi, h, pi, bt, *rest, j=j: (bt[bi, pi * ppb + j], 0, h, 0))
+            (1, page, hkv, d),
+            lambda bi, pi, bt, *rest, j=j: (bt[bi, pi * ppb + j], 0, 0, 0))
     return [spec(j) for j in range(ppb)] * 2
 
 
-def scale_block_specs(page: int, ppb: int):
+def scale_block_specs(page: int, hkv: int, ppb: int):
     """BlockSpecs of the per-page scale tiles ((P, page, hkv) arrays) a
     quantized arena streams beside its K/V pages — same block-table
-    walk, one (1, page, 1) column per page slot."""
+    walk, one whole (1, page, hkv) page per page slot."""
     def spec(j):
         return pl.BlockSpec(
-            (1, page, 1),
-            lambda bi, h, pi, bt, *rest, j=j: (bt[bi, pi * ppb + j], 0, h))
+            (1, page, hkv),
+            lambda bi, pi, bt, *rest, j=j: (bt[bi, pi * ppb + j], 0, 0))
     return [spec(j) for j in range(ppb)] * 2
+
+
+def carry_scratch(hkv: int, rows: int, d_pad: int):
+    """VMEM scratch of the per-head online-softmax carry."""
+    return [pltpu.VMEM((hkv, rows, 1), jnp.float32),       # running max
+            pltpu.VMEM((hkv, rows, 1), jnp.float32),       # running normalizer
+            pltpu.VMEM((hkv, rows, d_pad), jnp.float32)]   # running accumulator
+
+
+def carry_outputs(partials: bool, b: int, hkv: int, rows: int, d_pad: int,
+                  dtype):
+    """(out_shape, out_specs) of the walk: the normalized (b, hkv, rows,
+    d_pad) output, or with `partials` the raw (acc, m, l) carry in f32."""
+    def spec(last):
+        return pl.BlockSpec((1, hkv, rows, last),
+                            lambda bi, pi, *pref: (bi, 0, 0, 0))
+    if partials:
+        return ([jax.ShapeDtypeStruct((b, hkv, rows, d_pad), jnp.float32),
+                 jax.ShapeDtypeStruct((b, hkv, rows, 1), jnp.float32),
+                 jax.ShapeDtypeStruct((b, hkv, rows, 1), jnp.float32)],
+                [spec(d_pad), spec(1), spec(1)])
+    return ([jax.ShapeDtypeStruct((b, hkv, rows, d_pad), dtype)],
+            [spec(d_pad)])
+
+
+# batch cells are independent; the page walk carries VMEM state and must
+# stay in order ("arbitrary")
+COMPILER_PARAMS = pltpu.CompilerParams(
+    dimension_semantics=("parallel", "arbitrary"))
 
 
 def _paged_kernel(bt_ref, pos_ref, ppos_ref, q_ref, *refs,
-                  page_size: int, ppb: int, nb: int, d: int, d_pad: int,
-                  partials: bool, nscale: int = 0):
+                  page_size: int, ppb: int, nb: int, hkv: int, d: int,
+                  d_pad: int, partials: bool, nscale: int = 0):
     kv_refs = refs[:2 * ppb]
     scale_refs = refs[2 * ppb:2 * ppb + nscale] if nscale else None
     rest = refs[2 * ppb + nscale:]
@@ -221,18 +270,16 @@ def _paged_kernel(bt_ref, pos_ref, ppos_ref, q_ref, *refs,
     else:
         o_ref, m_scr, l_scr, acc_scr = rest
     bi = pl.program_id(0)
-    pi = pl.program_id(2)
+    pi = pl.program_id(1)
 
     @pl.when(pi == 0)
     def _init():
         reset_carry(m_scr, l_scr, acc_scr)
 
-    q = q_ref[0, 0]                                        # (g_pad, d_pad)
-    k, v = load_kv_block(kv_refs, ppb, d, d_pad, scale_refs)
-    s = jnp.dot(q, k.T, preferred_element_type=jnp.float32)
-    s = s / math.sqrt(d)                                   # (g_pad, ppb*page)
-    kv_pos = block_kv_positions(ppos_ref, bi, pi, ppb, page_size, s.shape[0])
-    accumulate_block(s, kv_pos <= pos_ref[bi], v, m_scr, l_scr, acc_scr)
+    kv_pos = block_kv_positions(ppos_ref, bi, pi, ppb, page_size,
+                                q_ref.shape[2])            # (g_pad, ppb*page)
+    attend_block(q_ref, kv_refs, scale_refs, kv_pos <= pos_ref[bi],
+                 m_scr, l_scr, acc_scr, hkv=hkv, ppb=ppb, d=d, d_pad=d_pad)
 
     @pl.when(pi == nb - 1)
     def _emit():
@@ -277,52 +324,30 @@ def paged_decode_attention_pallas(q, k_pages, v_pages, block_table,
         qg = jnp.pad(qg, ((0, 0), (0, 0),
                           (0, g_pad - group), (0, d_pad - d)))
 
-    if partials:
-        out_shape = [jax.ShapeDtypeStruct((b, hkv, g_pad, d_pad), jnp.float32),
-                     jax.ShapeDtypeStruct((b, hkv, g_pad, 1), jnp.float32),
-                     jax.ShapeDtypeStruct((b, hkv, g_pad, 1), jnp.float32)]
-        out_specs = [pl.BlockSpec((1, 1, g_pad, d_pad),
-                                  lambda bi, h, pi, *pref: (bi, h, 0, 0)),
-                     pl.BlockSpec((1, 1, g_pad, 1),
-                                  lambda bi, h, pi, *pref: (bi, h, 0, 0)),
-                     pl.BlockSpec((1, 1, g_pad, 1),
-                                  lambda bi, h, pi, *pref: (bi, h, 0, 0))]
-    else:
-        out_shape = [jax.ShapeDtypeStruct((b, hkv, g_pad, d_pad), q.dtype)]
-        out_specs = [pl.BlockSpec((1, 1, g_pad, d_pad),
-                                  lambda bi, h, pi, *pref: (bi, h, 0, 0))]
-
+    out_shape, out_specs = carry_outputs(partials, b, hkv, g_pad, d_pad,
+                                         q.dtype)
     quant = k_scale is not None
     nscale = 2 * ppb if quant else 0
     scale_args = ((*([k_scale] * ppb), *([v_scale] * ppb)) if quant else ())
 
-    # NOTE jax 0.4.x index-map convention: grid indices first, then the
-    # scalar-prefetch refs.
+    # index maps take the grid indices first, then the scalar-prefetch refs
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
-        grid=(b, hkv, nb),
-        in_specs=[pl.BlockSpec((1, 1, g_pad, d_pad),
-                               lambda bi, h, pi, *pref: (bi, h, 0, 0))]
-                 + kv_block_specs(page, d, ppb)
-                 + (scale_block_specs(page, ppb) if quant else []),
+        grid=(b, nb),
+        in_specs=[pl.BlockSpec((1, hkv, g_pad, d_pad),
+                               lambda bi, pi, *pref: (bi, 0, 0, 0))]
+                 + kv_block_specs(page, hkv, d, ppb)
+                 + (scale_block_specs(page, hkv, ppb) if quant else []),
         out_specs=out_specs,
-        scratch_shapes=[
-            pltpu.VMEM((g_pad, 1), jnp.float32),       # running max
-            pltpu.VMEM((g_pad, 1), jnp.float32),       # running normalizer
-            pltpu.VMEM((g_pad, d_pad), jnp.float32),   # running accumulator
-        ],
+        scratch_shapes=carry_scratch(hkv, g_pad, d_pad),
     )
     out = pl.pallas_call(
         functools.partial(_paged_kernel, page_size=page, ppb=ppb, nb=nb,
-                          d=d, d_pad=d_pad, partials=partials,
+                          hkv=hkv, d=d, d_pad=d_pad, partials=partials,
                           nscale=nscale),
         grid_spec=grid_spec,
         out_shape=out_shape,
-        compiler_params=pltpu.TPUCompilerParams(
-            # megacore split: (b, hkv) cells are independent and spread
-            # across both TensorCores; the page walk must stay in-order
-            # (VMEM carry), hence "arbitrary".
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        compiler_params=COMPILER_PARAMS,
         interpret=interpret,
     )(bt, positions.astype(jnp.int32), ppos, qg,
       *([k_pages] * ppb), *([v_pages] * ppb), *scale_args)

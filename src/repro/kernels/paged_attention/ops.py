@@ -1,16 +1,13 @@
-"""Jit'd wrapper for fused paged flash-decoding (interpret-mode path
-off-TPU)."""
+"""Jit'd wrapper for fused paged flash-decoding (the Pallas interpreter
+on the CPU backend, the compiled kernel elsewhere)."""
 from __future__ import annotations
 
 from functools import partial
 
 import jax
 
+from repro.kernels import interpret_default
 from repro.kernels.paged_attention import kernel as K
-
-
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
 
 
 @partial(jax.jit, static_argnames=("pages_per_block", "partials",
@@ -37,7 +34,7 @@ def paged_decode_attention(q, k_pages, v_pages, block_table, positions,
     `k_scale`/`v_scale` (optional (P, page, hkv) f32) are the per-token
     scale banks of a quantized (int8/fp8) arena — the kernel dequantizes
     each page tile in-register inside the page loop."""
-    interpret = (not _on_tpu()) if interpret is None else interpret
+    interpret = interpret_default(interpret)
     return K.paged_decode_attention_pallas(
         q, k_pages, v_pages, block_table, positions,
         pages_per_block=pages_per_block, page_positions=page_positions,

@@ -11,19 +11,20 @@ arena, and only the (b, c, hq, hd) chunk output leaves the kernel.
 
 Kernel geometry
 ---------------
-* **Grid (b, kv_heads, page_blocks)** — (b, hkv) are `parallel` (the
-  megacore split across the two TensorCores); the page-block dim is
+* **Grid (b, page_blocks)** — b is `parallel`; the page-block dim is
   `arbitrary` (SEQUENTIAL), walking each row's block table in order
-  while the online-softmax carry persists in VMEM scratch.
-* **Query tile** — the whole chunk rides in one (R, d_pad) VMEM tile
-  with chunk rows packed DENSELY along sublanes: row r of the score
-  tile is chunk position r // group, query-group member r % group, and
-  R = c*group rounds up to the 8-sublane f32 tile ONCE for the whole
-  chunk (not per row — a group-2 chunk costs 2 rows per position, not
-  8); the head dim pads to `d_pad` (128 lanes).
-* **VMEM carry** — running (m, l, acc) scratch of shapes `(R, 1)`,
-  `(R, 1)`, `(R, d_pad)` f32, initialized at page-block 0; the output
-  block is written once, at the LAST block.
+  while the online-softmax carry persists in VMEM scratch.  As in the
+  decode kernel, each K/V block is a whole page with every KV head
+  (one DMA per page per step), and the kernel loops over the heads.
+* **Query tile** — per KV head, the whole chunk rides in one
+  (R, d_pad) VMEM tile with chunk rows packed DENSELY along sublanes:
+  row r of the score tile is chunk position r // group, query-group
+  member r % group, and R = c*group rounds up to the 8-sublane f32
+  tile ONCE for the whole chunk (not per row — a group-2 chunk costs 2
+  rows per position, not 8); the head dim pads to `d_pad` (128 lanes).
+* **VMEM carry** — running (m, l, acc) scratch of shapes
+  `(hkv, R, 1)`, `(hkv, R, 1)`, `(hkv, R, d_pad)` f32, initialized at
+  page-block 0; the output block is written once, at the LAST block.
 * **Masking** — `start`-offset causal (kv_pos <= start[b] + chunk_row)
   AND ragged `chunk_len` (rows past chunk_len[b] are fully masked and
   emit exact zeros — inert bucket-tail rows are deterministic, never
@@ -35,7 +36,6 @@ Kernel geometry
 from __future__ import annotations
 
 import functools
-import math
 
 import jax
 import jax.numpy as jnp
@@ -43,14 +43,16 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.paged_attention.kernel import (
-    LANE, SUBLANE, _pad_block_table, _round_up, accumulate_block,
-    block_kv_positions, emit_output, emit_partials, kv_block_specs,
-    load_kv_block, reset_carry, default_page_positions, scale_block_specs)
+    COMPILER_PARAMS, LANE, SUBLANE, _pad_block_table, _round_up,
+    attend_block, block_kv_positions, carry_outputs, carry_scratch,
+    default_page_positions, emit_output, emit_partials, kv_block_specs,
+    reset_carry, scale_block_specs)
 
 
 def _prefill_kernel(bt_ref, start_ref, clen_ref, ppos_ref, q_ref, *refs,
-                    page_size: int, ppb: int, nb: int, group: int,
-                    d: int, d_pad: int, partials: bool, nscale: int = 0):
+                    page_size: int, ppb: int, nb: int, hkv: int,
+                    group: int, d: int, d_pad: int, partials: bool,
+                    nscale: int = 0):
     kv_refs = refs[:2 * ppb]
     scale_refs = refs[2 * ppb:2 * ppb + nscale] if nscale else None
     rest = refs[2 * ppb + nscale:]
@@ -59,25 +61,23 @@ def _prefill_kernel(bt_ref, start_ref, clen_ref, ppos_ref, q_ref, *refs,
     else:
         o_ref, m_scr, l_scr, acc_scr = rest
     bi = pl.program_id(0)
-    pi = pl.program_id(2)
+    pi = pl.program_id(1)
 
     @pl.when(pi == 0)
     def _init():
         reset_carry(m_scr, l_scr, acc_scr)
 
-    q = q_ref[0, 0]                                        # (R, d_pad)
-    k, v = load_kv_block(kv_refs, ppb, d, d_pad, scale_refs)
-    s = jnp.dot(q, k.T, preferred_element_type=jnp.float32)
-    s = s / math.sqrt(d)                                   # (R, ppb*page)
     # the decode kernel's machine with the chunk mask: start-offset
     # causal over absolute positions AND ragged chunk_len row validity
     # (tail rows and the sublane-padding rows past c*group get
     # ci >= chunk_len and end up exact zeros via the masked carry)
-    ci = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0) // group
+    kv_pos = block_kv_positions(ppos_ref, bi, pi, ppb, page_size,
+                                q_ref.shape[2])            # (R, ppb*page)
+    ci = jax.lax.broadcasted_iota(jnp.int32, kv_pos.shape, 0) // group
     q_pos = start_ref[bi] + ci                             # absolute position
-    kv_pos = block_kv_positions(ppos_ref, bi, pi, ppb, page_size, s.shape[0])
     valid = (kv_pos <= q_pos) & (ci < clen_ref[bi])
-    accumulate_block(s, valid, v, m_scr, l_scr, acc_scr)
+    attend_block(q_ref, kv_refs, scale_refs, valid, m_scr, l_scr, acc_scr,
+                 hkv=hkv, ppb=ppb, d=d, d_pad=d_pad)
 
     @pl.when(pi == nb - 1)
     def _emit():
@@ -127,49 +127,28 @@ def paged_prefill_attention_pallas(q, k_pages, v_pages, block_table, start,
     if R != rows:
         qg = jnp.pad(qg, ((0, 0), (0, 0), (0, R - rows), (0, 0)))
 
-    if partials:
-        out_shape = [jax.ShapeDtypeStruct((b, hkv, R, d_pad), jnp.float32),
-                     jax.ShapeDtypeStruct((b, hkv, R, 1), jnp.float32),
-                     jax.ShapeDtypeStruct((b, hkv, R, 1), jnp.float32)]
-        out_specs = [pl.BlockSpec((1, 1, R, d_pad),
-                                  lambda bi, h, pi, *pref: (bi, h, 0, 0)),
-                     pl.BlockSpec((1, 1, R, 1),
-                                  lambda bi, h, pi, *pref: (bi, h, 0, 0)),
-                     pl.BlockSpec((1, 1, R, 1),
-                                  lambda bi, h, pi, *pref: (bi, h, 0, 0))]
-    else:
-        out_shape = [jax.ShapeDtypeStruct((b, hkv, R, d_pad), q.dtype)]
-        out_specs = [pl.BlockSpec((1, 1, R, d_pad),
-                                  lambda bi, h, pi, *pref: (bi, h, 0, 0))]
-
+    out_shape, out_specs = carry_outputs(partials, b, hkv, R, d_pad, q.dtype)
     quant = k_scale is not None
     nscale = 2 * ppb if quant else 0
     scale_args = ((*([k_scale] * ppb), *([v_scale] * ppb)) if quant else ())
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=4,
-        grid=(b, hkv, nb),
-        in_specs=[pl.BlockSpec((1, 1, R, d_pad),
-                               lambda bi, h, pi, *pref: (bi, h, 0, 0))]
-                 + kv_block_specs(page, d, ppb)
-                 + (scale_block_specs(page, ppb) if quant else []),
+        grid=(b, nb),
+        in_specs=[pl.BlockSpec((1, hkv, R, d_pad),
+                               lambda bi, pi, *pref: (bi, 0, 0, 0))]
+                 + kv_block_specs(page, hkv, d, ppb)
+                 + (scale_block_specs(page, hkv, ppb) if quant else []),
         out_specs=out_specs,
-        scratch_shapes=[
-            pltpu.VMEM((R, 1), jnp.float32),       # running max
-            pltpu.VMEM((R, 1), jnp.float32),       # running normalizer
-            pltpu.VMEM((R, d_pad), jnp.float32),   # running accumulator
-        ],
+        scratch_shapes=carry_scratch(hkv, R, d_pad),
     )
     out = pl.pallas_call(
         functools.partial(_prefill_kernel, page_size=page, ppb=ppb, nb=nb,
-                          group=group, d=d, d_pad=d_pad, partials=partials,
-                          nscale=nscale),
+                          hkv=hkv, group=group, d=d, d_pad=d_pad,
+                          partials=partials, nscale=nscale),
         grid_spec=grid_spec,
         out_shape=out_shape,
-        compiler_params=pltpu.TPUCompilerParams(
-            # megacore split over (b, hkv); the page walk carries VMEM
-            # state and must stay sequential.
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        compiler_params=COMPILER_PARAMS,
         interpret=interpret,
     )(bt, start.astype(jnp.int32), chunk_len.astype(jnp.int32), ppos, qg,
       *([k_pages] * ppb), *([v_pages] * ppb), *scale_args)

@@ -1,16 +1,13 @@
-"""Jit'd wrapper for fused paged chunk-prefill attention (interpret-mode
-path off-TPU)."""
+"""Jit'd wrapper for fused paged chunk-prefill attention (the Pallas
+interpreter on the CPU backend, the compiled kernel elsewhere)."""
 from __future__ import annotations
 
 from functools import partial
 
 import jax
 
+from repro.kernels import interpret_default
 from repro.kernels.paged_prefill import kernel as K
-
-
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
 
 
 @partial(jax.jit, static_argnames=("pages_per_block", "partials",
@@ -34,7 +31,7 @@ def paged_prefill_attention(q, k_pages, v_pages, block_table, start,
     `k_scale`/`v_scale` (optional (P, page, hkv) f32) are a quantized
     arena's per-token scale banks — dequantized in-register inside the
     kernel's page loop."""
-    interpret = (not _on_tpu()) if interpret is None else interpret
+    interpret = interpret_default(interpret)
     return K.paged_prefill_attention_pallas(
         q, k_pages, v_pages, block_table, start, chunk_len,
         pages_per_block=pages_per_block, page_positions=page_positions,

@@ -5,14 +5,11 @@ from functools import partial
 
 import jax
 
+from repro.kernels import interpret_default
 from repro.kernels.ssd_scan import kernel as K
-
-
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
 
 
 @partial(jax.jit, static_argnames=("interpret",))
 def ssd_intra_chunk(x, dt, A, B, C, interpret=None):
-    interpret = (not _on_tpu()) if interpret is None else interpret
+    interpret = interpret_default(interpret)
     return K.ssd_intra_chunk_pallas(x, dt, A, B, C, interpret=interpret)

@@ -1,8 +1,7 @@
 """Jit'd wrappers for the weight-stationary matmul kernels.
 
-On CPU (this container) the Pallas TPU pipeline is unavailable, so the
-wrappers run the kernel body under `interpret=True` (tests) or fall back
-to the XLA oracle (production paths pick the kernel only on TPU).
+On the CPU backend the wrappers run the kernel body in the Pallas
+interpreter; elsewhere the compiled kernel runs.
 """
 from __future__ import annotations
 
@@ -10,19 +9,16 @@ from functools import partial
 
 import jax
 
+from repro.kernels import interpret_default
 from repro.kernels.ws_matmul import kernel as K
 from repro.kernels.ws_matmul.ref import matmul_ref
-
-
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
 
 
 @partial(jax.jit, static_argnames=("block_m", "block_n", "block_k", "interpret"))
 def ws_matmul(x, w, block_m=K.DEF_BM, block_n=K.DEF_BN, block_k=K.DEF_BK,
               interpret=None):
-    """Weight-stationary matmul; interpret defaults to True off-TPU."""
-    interpret = (not _on_tpu()) if interpret is None else interpret
+    """Weight-stationary matmul; interpret defaults to True on the CPU."""
+    interpret = interpret_default(interpret)
     return K.ws_matmul_pallas(x, w, block_m=block_m, block_n=block_n,
                               block_k=block_k, interpret=interpret)
 
@@ -31,6 +27,6 @@ def ws_matmul(x, w, block_m=K.DEF_BM, block_n=K.DEF_BN, block_k=K.DEF_BK,
 def os_matmul(x, w, block_m=K.DEF_BM, block_n=K.DEF_BN, block_k=K.DEF_BK,
               interpret=None):
     """Output-stationary ablation twin."""
-    interpret = (not _on_tpu()) if interpret is None else interpret
+    interpret = interpret_default(interpret)
     return K.os_matmul_pallas(x, w, block_m=block_m, block_n=block_n,
                               block_k=block_k, interpret=interpret)

@@ -10,35 +10,22 @@ Axis roles (DESIGN.md §5): FSDP/DP over ("pod", "data") — the DSU pool
 serving feature data; TP/SP/EP over "model" — the VPU pool holding
 resident weight shards.
 
-`jax.sharding.AxisType` only exists on jax >= 0.5; on 0.4.x meshes are
-implicitly Auto-typed, so every mesh in the repo is built through the
-compat constructors here rather than importing AxisType directly.
+Every mesh in the repo is built here, with Auto-typed axes.
 """
 from __future__ import annotations
 
 import jax
-from jax.sharding import Mesh
-
-try:  # jax >= 0.5
-    from jax.sharding import AxisType
-except ImportError:  # jax 0.4.x: meshes are Auto-typed by construction
-    AxisType = None
+from jax.sharding import AbstractMesh, AxisType, Mesh
 
 
 def _mk(shape, axes) -> Mesh:
-    if AxisType is not None:
-        return jax.make_mesh(shape, axes,
-                             axis_types=(AxisType.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_abstract_mesh(shape, axes):
     """Device-free mesh for spec resolution (tests, planning tools)."""
-    from jax.sharding import AbstractMesh
-    if AxisType is not None:
-        return AbstractMesh(tuple(shape), tuple(axes),
-                            axis_types=(AxisType.Auto,) * len(axes))
-    return AbstractMesh(tuple(zip(axes, shape)))
+    return AbstractMesh(tuple(shape), tuple(axes),
+                        axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:

@@ -52,6 +52,7 @@ from repro.configs import get_arch
 from repro.models.config import reduced_for_smoke
 from repro.models import registry
 from repro.serve import ServingEngine, Request, SamplingParams
+from repro.utils.compile_cache import use_compile_cache
 from repro.utils.logging import get_logger
 
 log = get_logger("serve")
@@ -143,6 +144,7 @@ def main(argv=None):
                     help="per-tenant weighted max-min token-budget shares, "
                          "e.g. 'alpha:3,beta:1' (unnamed tenants weigh 1)")
     args = ap.parse_args(argv)
+    use_compile_cache()
 
     spec = get_arch(args.arch)
     cfg = spec.model

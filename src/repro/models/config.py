@@ -28,7 +28,10 @@ class ModelConfig:
     head_dim: int = 0
     rope_theta: float = 10_000.0
     causal: bool = True
-    attention_impl: str = "flash_xla"    # dense | flash_xla | flash_pallas
+    # dense | flash_xla | flash_pallas.  The paged serving walk runs the
+    # fused Pallas kernels off the CPU whatever this says
+    # (`layers.fused_paged_kernels`).
+    attention_impl: str = "flash_xla"
     attn_chunk: int = 1024               # KV block for online-softmax attention
     attn_pages_per_block: int = 1        # arena pages per paged-kernel grid cell
     # bound mesh axis the paged KV arena is sharded over (set only inside

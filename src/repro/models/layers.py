@@ -206,9 +206,8 @@ def _shard_local_walk(mem_axis: str, block_table, page_size: int,
     attention walk is n times shorter — KV bandwidth scales with the
     mesh."""
     from repro.kernels.paged_attention.kernel import POS_PAD
-    from repro.distribution.collectives import axis_size
 
-    n = axis_size(mem_axis)
+    n = jax.lax.axis_size(mem_axis)
     idx = jax.lax.axis_index(mem_axis)
     pps = local_null                       # bank size == local null slot
     b, mp = block_table.shape
@@ -226,6 +225,15 @@ def _shard_local_walk(mem_axis: str, block_table, page_size: int,
     return lbt, page_pos.astype(jnp.int32)
 
 
+def fused_paged_kernels(cfg: ModelConfig) -> bool:
+    """Whether the paged walk runs the fused Pallas kernels.  Off the
+    CPU backend it always does, whatever `attention_impl` says.  On the
+    CPU the XLA gather oracle serves, unless the config asks for
+    `flash_pallas` (the kernels then run in the Pallas interpreter)."""
+    return (cfg.attention_impl == "flash_pallas"
+            or jax.default_backend() != "cpu")
+
+
 def run_paged_decode_attention(cfg: ModelConfig, q, k_pages, v_pages,
                                block_table, positions,
                                k_scale=None, v_scale=None):
@@ -233,11 +241,11 @@ def run_paged_decode_attention(cfg: ModelConfig, q, k_pages, v_pages,
 
     q: (b, hq, d); k_pages/v_pages: (P, page, hkv, d) ONE layer's
     physical page arena; block_table: (b, max_pages) page-table rows;
-    positions: (b,) inclusive newest index.  flash_pallas routes to the
-    fused single-pass Pallas block-table kernel (resident pages,
-    travelling query, VMEM online-softmax carry —
-    `cfg.attn_pages_per_block` pages per sequential grid cell); other
-    impls use the XLA gather oracle.  Returns (b, hq*d).
+    positions: (b,) inclusive newest index.  Routes to the fused
+    single-pass Pallas block-table kernel (resident pages, travelling
+    query, VMEM online-softmax carry — `cfg.attn_pages_per_block` pages
+    per sequential grid cell), or on the CPU to the XLA gather oracle
+    (`fused_paged_kernels`).  Returns (b, hq*d).
 
     With `cfg.mem_axis` set (inside the shard_map'd sharded serving
     step, where `block_table` carries GLOBAL pool ids) each chip
@@ -259,7 +267,7 @@ def run_paged_decode_attention(cfg: ModelConfig, q, k_pages, v_pages,
             local_null=k_pages.shape[0] - 1)
         block_table = lbt
         kw.update(page_positions=page_pos, partials=True)
-    if cfg.attention_impl == "flash_pallas":
+    if fused_paged_kernels(cfg):
         from repro.kernels.paged_attention.ops import paged_decode_attention
         o = paged_decode_attention(q, k_pages, v_pages, block_table, positions,
                                    pages_per_block=cfg.attn_pages_per_block,
@@ -282,10 +290,10 @@ def run_paged_prefill_attention(cfg: ModelConfig, q, k_pages, v_pages,
     q: (b, c, hq, d) chunk queries at absolute positions
     start[i]..start[i]+c-1; k_pages/v_pages: (P, page, hkv, d) ONE
     layer's arena (the chunk's own K/V already written); chunk_len: (b,)
-    ragged valid rows (rows past it come back as zeros).  flash_pallas
-    walks the block table inside the fused Pallas kernel — the
-    (b, max_pages*page, hkv, hd) gathered KV copy of the old
-    formulation never exists; other impls use the XLA gather oracle.
+    ragged valid rows (rows past it come back as zeros).  The fused
+    Pallas kernel walks the block table in place — the
+    (b, max_pages*page, hkv, hd) gathered KV copy of the XLA gather
+    oracle (the CPU path, `fused_paged_kernels`) never exists.
     Returns (b, c, hq*d).  Per-chunk cost is c*S, not prompt^2.
 
     With `cfg.mem_axis` set (sharded serving step, GLOBAL block table),
@@ -302,7 +310,7 @@ def run_paged_prefill_attention(cfg: ModelConfig, q, k_pages, v_pages,
             local_null=k_pages.shape[0] - 1)
         block_table = lbt
         kw.update(page_positions=page_pos, partials=True)
-    if cfg.attention_impl == "flash_pallas":
+    if fused_paged_kernels(cfg):
         from repro.kernels.paged_prefill.ops import paged_prefill_attention
         o = paged_prefill_attention(q, k_pages, v_pages, block_table,
                                     start, chunk_len,
@@ -440,7 +448,6 @@ def _vocab_parallel_lookup(emb, cfg: ModelConfig, tokens, mesh):
     ids in ITS vocab range from its RESIDENT table rows (masked-local),
     then one activation-sized psum combines.  The backward is a LOCAL
     scatter-add — the table-sized gradient never crosses the fabric."""
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
     from repro.distribution.sharding import logical_to_spec
     from functools import partial
@@ -459,8 +466,8 @@ def _vocab_parallel_lookup(emb, cfg: ModelConfig, tokens, mesh):
         x = jnp.where(ok[..., None], x.astype(dtype), jnp.zeros((), dtype))
         return jax.lax.psum(x, "model")
 
-    fn = shard_map(local, mesh=mesh, in_specs=(emb_spec, tok_spec),
-                   out_specs=out_spec, check_rep=False)
+    fn = jax.shard_map(local, mesh=mesh, in_specs=(emb_spec, tok_spec),
+                       out_specs=out_spec, check_vma=False)
     return fn(emb, tokens)
 
 

@@ -254,7 +254,6 @@ def _moe_ep_local(cfg: ModelConfig, model_axis: str, other_axes: tuple,
 def _moe_ep(p, cfg: ModelConfig, xf):
     """Expert-parallel dispatch via shard_map; falls back to the global
     scatter when no mesh (or an indivisible expert count) is active."""
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
     from repro.distribution.sharding import current_mesh, logical_to_spec
     from functools import partial
@@ -266,12 +265,12 @@ def _moe_ep(p, cfg: ModelConfig, xf):
     other = tuple(a for a in mesh.axis_names if a != "model")
     x_spec = logical_to_spec(("act_batch", None), tuple(xf.shape), mesh)
     w_spec = P("model", None, None)
-    fn = shard_map(
+    fn = jax.shard_map(
         partial(_moe_ep_local, cfg, "model", other),
         mesh=mesh,
         in_specs=(P(), w_spec, w_spec, w_spec, x_spec),
         out_specs=(x_spec, P()),
-        check_rep=False,
+        check_vma=False,
     )
     return fn(p["router"], p["experts"]["wg"], p["experts"]["wi"],
               p["experts"]["wo"], xf)

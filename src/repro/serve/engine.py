@@ -298,6 +298,12 @@ class ServingEngine:
         if layout != "paged":
             self.mesh = None        # only the arena shards; contiguous
                                     # (ssm fallback) serves single-device
+        if self.mesh is not None:
+            # every chip of the mesh holds the weights: place them once,
+            # or each step would broadcast them from where they were made
+            from jax.sharding import NamedSharding, PartitionSpec
+            self.params = params = jax.device_put(
+                params, NamedSharding(self.mesh, PartitionSpec()))
         pool_pages = pool_pages or (max_batch * max_seq) // page_size
         self.max_pages = -(-max_seq // page_size)     # block-table width
         self.prefill_chunk = prefill_chunk or max(page_size * 4, 32)

@@ -22,6 +22,7 @@ placement and re-gather the whole arena onto one device.
 """
 from __future__ import annotations
 
+import functools
 from dataclasses import dataclass, field
 
 import jax
@@ -59,17 +60,24 @@ class ShardedPagedKVArena(PagedKVArena):
             raise ValueError(f"num_pages {self.num_pages} must divide over "
                              f"{n} shards")
         pps = self.num_pages // n
+
+        def sharding(name):
+            return NamedSharding(self.mesh, P(None, MEM_AXIS)
+                                 if is_page_leaf(name) else P())
+
         if self.kv is None:
+            # built in place, bank by bank: the whole arena never sits
+            # on one device
             from repro.models import registry
             fam = registry.get_family(self.cfg)
-            self.kv = fam.init_paged_cache(
-                self.cfg, n * (pps + 1), self.page_size, self.max_batch)
-        self.kv = {
-            name: jax.device_put(
-                a, NamedSharding(self.mesh,
-                                 P(None, MEM_AXIS) if is_page_leaf(name)
-                                 else P()))
-            for name, a in self.kv.items()}
+            init = functools.partial(fam.init_paged_cache, self.cfg,
+                                     n * (pps + 1), self.page_size,
+                                     self.max_batch)
+            shapes = jax.eval_shape(init)
+            self.kv = jax.jit(init, out_shardings={
+                name: sharding(name) for name in shapes})()
+        self.kv = {name: jax.device_put(a, sharding(name))
+                   for name, a in self.kv.items()}
         if self.pool is None:
             self.pool = ShardedUniMemPool(self.num_pages, self.page_size,
                                           num_shards=n)

@@ -32,7 +32,6 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 from repro.core.unimem import is_page_leaf
 from repro.launch.mesh import MEM_AXIS
@@ -65,19 +64,19 @@ def make_sharded_serve_fns(cfg: ModelConfig, mesh: Mesh, num_pages: int,
     def prefill_body(params, chunk, arena, bt, start, clen):
         return fam.paged_prefill(params, scfg, chunk, arena, bt, start, clen)
 
-    prefill_sharded = shard_map(
+    prefill_sharded = jax.shard_map(
         prefill_body, mesh=mesh,
         in_specs=(rep, rep, arena_specs, rep, rep, rep),
-        out_specs=(arena_specs, rep), check_rep=False)
+        out_specs=(arena_specs, rep), check_vma=False)
 
     def decode_body(params, arena, bt, positions, tokens):
         return fam.paged_decode_step(params, scfg, arena, bt, positions,
                                      tokens)
 
-    decode_sharded = shard_map(
+    decode_sharded = jax.shard_map(
         decode_body, mesh=mesh,
         in_specs=(rep, arena_specs, rep, rep, rep),
-        out_specs=(arena_specs, rep), check_rep=False)
+        out_specs=(arena_specs, rep), check_vma=False)
 
     @partial(jax.jit, donate_argnums=() if cpu else (2,))
     def prefill_chunk(params, chunk, arena, block_table, start, chunk_len,
@@ -119,10 +118,10 @@ def make_sharded_verify_fn(cfg: ModelConfig, mesh: Mesh, num_pages: int,
     def verify_body(params, chunk, arena, bt, start, clen):
         return fam.paged_verify(params, scfg, chunk, arena, bt, start, clen)
 
-    verify_sharded = shard_map(
+    verify_sharded = jax.shard_map(
         verify_body, mesh=mesh,
         in_specs=(rep, rep, arena_specs, rep, rep, rep),
-        out_specs=(arena_specs, rep), check_rep=False)
+        out_specs=(arena_specs, rep), check_vma=False)
 
     @partial(jax.jit, donate_argnums=() if cpu else (2,))
     def verify(params, chunk, arena, block_table, start, chunk_len, draft,

@@ -104,3 +104,29 @@ def test_model_flops_decode_uses_one_token():
     f_dec = model_flops(cfg, SHAPES["decode_32k"])
     f_pre = model_flops(cfg, SHAPES["prefill_32k"])
     assert f_dec < f_pre / 1000                 # decode is 1 token/seq
+
+
+@pytest.mark.parametrize("from_env", [False, True])
+def test_compile_cache_follows_env_else_fixed_checkout_path(
+        monkeypatch, tmp_path, from_env):
+    """`JAX_COMPILATION_CACHE_DIR` places the persistent cache; without
+    it the cache sits at one fixed, git-ignored path in the checkout."""
+    from pathlib import Path
+
+    import jax
+    from repro.utils import compile_cache as cc
+
+    repo = Path(__file__).resolve().parents[1]
+    if from_env:
+        want = str(tmp_path / "cache")
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", want)
+    else:
+        want = str(repo / ".jax_cache")
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        assert ".jax_cache/" in (repo / ".gitignore").read_text().split()
+    prev = jax.config.jax_compilation_cache_dir
+    try:
+        assert cc.use_compile_cache() == want
+        assert jax.config.jax_compilation_cache_dir == want
+    finally:
+        jax.config.update("jax_compilation_cache_dir", prev)

@@ -105,7 +105,6 @@ def test_int8_compressed_psum_close_to_exact():
     run_with_devices("""
         import numpy as np, jax, jax.numpy as jnp
         from jax.sharding import PartitionSpec as P
-        from jax.experimental.shard_map import shard_map
         from repro.distribution.collectives import ring_allreduce_int8
         from repro.launch.mesh import make_mesh
 
@@ -117,8 +116,9 @@ def test_int8_compressed_psum_close_to_exact():
             approx = ring_allreduce_int8(xl[0], "pod")
             return exact[0], approx
 
-        exact, approx = shard_map(body, mesh=mesh, in_specs=P("pod"),
-                                  out_specs=(P(), P()), check_rep=False)(x)
+        exact, approx = jax.shard_map(body, mesh=mesh, in_specs=P("pod"),
+                                      out_specs=(P(), P()),
+                                      check_vma=False)(x)
         err = np.abs(np.asarray(exact) - np.asarray(approx))
         rel = err.max() / np.abs(np.asarray(exact)).max()
         assert rel < 0.02, rel          # int8 wire: ~1% worst-case error

@@ -93,6 +93,46 @@ GEOMETRIES = [
 ]
 
 
+
+# ------------------------------------ which path the paged walk takes
+
+@pytest.mark.parametrize("backend,impl,fused", [
+    ("cpu", "flash_xla", False),      # the published configs' default
+    ("cpu", "dense", False),
+    ("cpu", "flash_pallas", True),    # the interpret-mode parity tests
+    ("tpu", "flash_xla", True),
+    ("tpu", "dense", True),
+    ("gpu", "flash_xla", True),
+])
+def test_paged_walk_runs_fused_kernels_off_the_cpu(monkeypatch, backend,
+                                                   impl, fused):
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    assert L.fused_paged_kernels(TINY["dense"].replace(
+        attention_impl=impl)) is fused
+
+
+@pytest.mark.parametrize("backend", ["cpu", "tpu", "gpu", "unknown"])
+@pytest.mark.parametrize("which", ["decode", "prefill"])
+def test_pallas_interpreter_serves_the_cpu_only(monkeypatch, backend, which):
+    """The wrappers pick the interpreter for the CPU backend and the
+    compiled kernel for every other, an unknown one included."""
+    import repro.kernels.paged_attention.kernel as KD
+    import repro.kernels.paged_prefill.kernel as KP
+    from repro.kernels.paged_attention import ops as dec_ops
+    from repro.kernels.paged_prefill import ops as pre_ops
+
+    seen = []
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    if which == "decode":
+        monkeypatch.setattr(KD, "paged_decode_attention_pallas",
+                            lambda *a, interpret, **k: seen.append(interpret))
+        dec_ops.paged_decode_attention.__wrapped__(*[None] * 5)
+    else:
+        monkeypatch.setattr(KP, "paged_prefill_attention_pallas",
+                            lambda *a, interpret, **k: seen.append(interpret))
+        pre_ops.paged_prefill_attention.__wrapped__(*[None] * 6)
+    assert seen == [backend == "cpu"]
+
 def _geom_setup(rng, b, hd, page, mp, hkv):
     P = b * mp + 1
     k_pages = jnp.asarray(rng.standard_normal((P, page, hkv, hd)), jnp.float32)

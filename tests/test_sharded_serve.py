@@ -333,6 +333,34 @@ def test_sharded_arena_matches_single_device_tokens(family):
 
 
 @pytest.mark.slow
+def test_sharded_engine_places_weights_and_banks_on_every_device():
+    """On a mesh the engine holds the weights replicated on every
+    device (placed once, not broadcast per step) and each device holds
+    exactly its own bank of the arena."""
+    run_with_devices("""
+        import jax
+        from conftest import TINY
+        from repro.launch.mesh import make_mem_mesh
+        from repro.models import registry
+        from repro.serve import ServingEngine
+
+        cfg = TINY["dense"]
+        params = registry.get_family(cfg).init(jax.random.key(0), cfg)
+        mesh = make_mem_mesh(4)
+        eng = ServingEngine(cfg, params, max_batch=2, max_seq=64,
+                            page_size=8, mesh=mesh)
+        devs = set(mesh.devices.flat)
+        for leaf in jax.tree.leaves(eng.params):
+            assert leaf.sharding.is_fully_replicated, leaf.sharding
+            assert leaf.sharding.device_set == devs
+        k = eng.arena.kv["k"]
+        shards = k.addressable_shards
+        assert {s.device for s in shards} == devs
+        assert all(s.data.shape[1] == k.shape[1] // 4 for s in shards)
+    """, n=4)
+
+
+@pytest.mark.slow
 def test_sharded_step_collectives_are_summary_sized():
     """The interconnect contract on COMPILED HLO: the jitted sharded
     decode step merges per-shard softmax summaries — every collective's

@@ -1,7 +1,6 @@
 """Sharding rule engine: divisibility fallback, spec resolution, and the
 weight-stationarity HLO audit.  Property-style tests are parametrized
-sweeps (no hypothesis dependency); meshes come from the launch.mesh
-compat layer so the suite runs on jax 0.4.x and 0.5+ alike."""
+sweeps (no hypothesis dependency); meshes come from `launch.mesh`."""
 from __future__ import annotations
 
 import numpy as np
