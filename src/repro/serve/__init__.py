@@ -22,6 +22,9 @@ Architecture (one pooled memory, the paper's form):
     serve/engine.py          continuous batching: lazy allocation,
                              chunked prefill, prefix sharing, preemption,
                              the TokenEvent/FinishEvent stream
+    serve/tracing.py         host spans of the tick's phases and each
+                             request's queue wait, in a bounded ring,
+                             also written into a running profiler's trace
     serve/api.py             public facade: LLMServer.generate ->
                              GenerationStream (+ fork under a new
                              sampling regime over shared COW pages,

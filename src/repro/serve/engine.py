@@ -110,6 +110,7 @@ from repro.core.unimem import (HostParcel, HostTier, SequencePageTable,
 from repro.models.config import ModelConfig
 from repro.models import registry
 from repro.serve.kv_cache import PagedKVArena, insert_slot, clear_slot
+from repro.serve import tracing
 from repro.serve.prefix_store import PrefixStore
 from repro.serve.sampling import (SamplingParams, state_for_slots,
                                   sample as sample_on_device)
@@ -428,6 +429,10 @@ class ServingEngine:
         self._admitted = 0
         self._events: deque = deque()
         self._emitted: dict[int, int] = {}       # uid -> tokens published
+        # spans (serve/tracing.py): this engine's id, and when each
+        # queued request (re-)entered `pending`, for its `engine.queue`
+        self.trace_id = tracing.next_engine_id()
+        self._queued_at: dict[int, int] = {}     # uid -> perf_counter_ns
 
     # ------------------------------------------------------------ intake
 
@@ -466,7 +471,18 @@ class ServingEngine:
                 f"request {request.uid}: {self.cfg.family} requests need "
                 f"patch_embeds with {self.cfg.num_patches} rows, got "
                 f"{request.num_patch_tokens}")
+        self._queued_at[request.uid] = time.perf_counter_ns()
         self.pending.append(request)
+
+    def _dequeue(self, pidx: int) -> Request:
+        """Take `pending[pidx]` off the queue to admit it; its wait since
+        it (re-)entered the queue becomes an `engine.queue` span."""
+        req = self.pending.pop(pidx)
+        t0 = self._queued_at.pop(req.uid, None)
+        if t0 is not None:
+            tracing.record("engine.queue", t0, time.perf_counter_ns(),
+                           uid=req.uid)
+        return req
 
     def _free_slots(self) -> list[int]:
         return [i for i in range(self.max_batch) if i not in self.slots]
@@ -505,10 +521,13 @@ class ServingEngine:
         return sampled
 
     def _emit_decoded(self, active: dict[int, _Slot], next_tokens) -> None:
-        """Shared retire-and-emit tail of both decode layouts."""
-        next_tokens = np.asarray(next_tokens)
-        for i, s in active.items():
-            self._emit(s, self._next_token(s, int(next_tokens[i])))
+        """Shared tail of both decode layouts: the wait for the step's
+        tokens, then their emission, each a span of its own."""
+        with tracing.span("engine.decode.readback"):
+            next_tokens = np.asarray(next_tokens)
+        with tracing.span("engine.decode.emit"):
+            for i, s in active.items():
+                self._emit(s, self._next_token(s, int(next_tokens[i])))
 
     def events(self) -> list:
         """Drain pending TokenEvent/FinishEvent records (FIFO)."""
@@ -794,7 +813,7 @@ class ServingEngine:
             if not self._fits_or_reclaim(rot + len(written) + len(adopted),
                                          need, protect=set(store_hashes)):
                 break                            # UniMem backpressure
-            self.pending.pop(pidx)
+            self._dequeue(pidx)
             slot = free.pop(0)
             if written or adopted:
                 self.pool.share(written + adopted)
@@ -817,7 +836,7 @@ class ServingEngine:
             req = self.pending[0]
             if not self.pool.can_admit(req.max_footprint):
                 break                            # UniMem backpressure
-            self.pending.pop(0)
+            self._dequeue(0)
             slot = free.pop(0)
             pages = SequencePageTable(self.pool)
             pages.append_tokens(req.max_footprint)
@@ -826,7 +845,9 @@ class ServingEngine:
             batch = {"tokens": jnp.asarray(req.prompt, jnp.int32)[None, :]}
             if req.patch_embeds is not None:
                 batch["patch_embeds"] = jnp.asarray(req.patch_embeds)[None]
-            one_cache, logits = self.prefill_fn(self.params, batch, one_cache)
+            with tracing.span("engine.prefill.dispatch", rows=1, batch=1):
+                one_cache, logits = self.prefill_fn(self.params, batch,
+                                                    one_cache)
             self.prefill_tokens += req.virtual_len
             self.cache = insert_slot(self.cache, one_cache, slot, self.cache_ax)
             s = _Slot(request=req, pages=pages,
@@ -878,14 +899,40 @@ class ServingEngine:
         prompts never freeze token emission.  Under a token-budget tick
         the chunk lengths are additionally capped oldest-first by the
         prefill share of `tick_token_budget`."""
-        if self.layout != "paged":
+        if self.layout != "paged" or not any(
+                s.prefilling for s in self.slots.values()):
             return
+        with tracing.span("engine.prefill.build"):
+            batch = self._prefill_batch()
+        if batch is None:
+            return
+        pre, chunk, bt, start, clen, st = batch
+        b, c = chunk["tokens"].shape
+        with tracing.span("engine.prefill.dispatch", rows=len(pre), batch=b):
+            self.arena.kv, first = self.prefill_fn(
+                self.params, chunk, self.arena.kv, bt, start, clen, st)
+        self.prefill_shapes.add((b, c))
+        self.prefill_tokens += int(clen.sum())
+        with tracing.span("engine.prefill.readback"):
+            first = np.asarray(first)
+        with tracing.span("engine.prefill.emit"):
+            for i, s in pre:
+                s.prefill_pos += int(clen[i])
+                self._register_prefix(s)         # newly-written full pages
+                if not s.prefilling:             # prompt complete: the
+                                                 # step sampled token 0
+                    self._emit(s, self._next_token(s, int(first[i])))
+
+    def _prefill_batch(self):
+        """This tick's prefill rows and the step's arguments: (rows,
+        chunk, block table, start, chunk_len, sampling state), or None
+        when no row advances."""
         pre = [(i, s) for i, s in self.slots.items() if s.prefilling]
         for _, s in pre:
             self._absorb_shared(s)
         pre = [(i, s) for i, s in pre if s.prefilling]
         if not pre:
-            return
+            return None
         lens = {i: min(self.prefill_chunk,
                        s.request.virtual_len - s.prefill_pos)
                 for i, s in pre}
@@ -912,7 +959,7 @@ class ServingEngine:
                     caps[t] = caps.get(t, 0) - lens[i]
             pre = [(i, s) for i, s in pre if lens[i] > 0]
             if not pre:
-                return
+                return None
         # lazy prompt-page growth (watermark admission allocated only the
         # first chunk): extend each slot's table to cover this tick's
         # chunk, preempting younger slots under pool pressure — a slot
@@ -926,7 +973,7 @@ class ServingEngine:
                     s, lambda s=s, g=grow: s.pages.append_tokens(g))
         pre = [(i, s) for i, s in pre if self.slots.get(i) is s]
         if not pre:
-            return
+            return None
         lens = {i: lens[i] for i, _ in pre}
         b, c = self.max_batch, self._bucket_width(max(lens.values()))
         tokens = np.zeros((b, c), np.int32)
@@ -952,18 +999,7 @@ class ServingEngine:
         chunk = {"tokens": tokens}
         if patches is not None:
             chunk["patches"] = patches
-        self.arena.kv, first = self.prefill_fn(
-            self.params, chunk, self.arena.kv, bt, start, clen,
-            self._sampling_state(dict(pre)))
-        self.prefill_shapes.add((b, c))
-        self.prefill_tokens += int(clen.sum())
-        first = np.asarray(first)
-        for i, s in pre:
-            s.prefill_pos += int(clen[i])
-            self._register_prefix(s)             # newly-written full pages
-            if not s.prefilling:                 # prompt complete: the
-                                                 # step sampled token 0
-                self._emit(s, self._next_token(s, int(first[i])))
+        return pre, chunk, bt, start, clen, self._sampling_state(dict(pre))
 
     # ------------------------------------------------------------- step
 
@@ -1018,6 +1054,7 @@ class ServingEngine:
         self._drop_store_refs(victim)
         self._release_pages(victim.pages)
         del self.slots[idx]
+        self._queued_at[victim.request.uid] = time.perf_counter_ns()
         self.pending.insert(0, victim.request)
 
     def _preempt_youngest(self, but: _Slot) -> bool:
@@ -1098,7 +1135,7 @@ class ServingEngine:
         pre = self._prefetched.pop(req.uid, None)
         payload = pre[1] if pre is not None and pre[0] is parcel \
             else parcel.data
-        self.pending.pop(pidx)
+        self._dequeue(pidx)
         slot = free.pop(0)
         seq = SequencePageTable(self.pool, rotation=rot)
         seq.append_tokens(parcel.meta["tokens"])
@@ -1184,26 +1221,30 @@ class ServingEngine:
     def _decode_plain(self, active: dict[int, _Slot]):
         if not active:
             return
-        # grow tables first (may preempt younger slots under pool pressure)
-        for i, s in list(active.items()):
-            if self.slots.get(i) is not s:
-                continue                         # already preempted this step
-            self._grow_for_write(s)
-        active = {i: s for i, s in active.items() if self.slots.get(i) is s}
-        if not active:
-            return
-
-        tokens = np.zeros((self.max_batch,), np.int32)
-        positions = np.zeros((self.max_batch,), np.int32)
-        bt = np.full((self.max_batch, self.max_pages), self.arena.null_page,
-                     np.int32)
-        for i, s in active.items():
-            tokens[i] = s.last_token
-            positions[i] = s.pages.num_tokens - 1   # slot appended above
-            bt[i, :len(s.pages.pages)] = s.pages.pages
-        self.arena.kv, nxt = self.decode_fn(
-            self.params, self.arena.kv, bt, positions, tokens,
-            self._sampling_state(active))
+        with tracing.span("engine.decode.build"):
+            # grow tables first (may preempt younger slots under pool
+            # pressure)
+            for i, s in list(active.items()):
+                if self.slots.get(i) is not s:
+                    continue                     # already preempted this step
+                self._grow_for_write(s)
+            active = {i: s for i, s in active.items()
+                      if self.slots.get(i) is s}
+            if not active:
+                return
+            tokens = np.zeros((self.max_batch,), np.int32)
+            positions = np.zeros((self.max_batch,), np.int32)
+            bt = np.full((self.max_batch, self.max_pages),
+                         self.arena.null_page, np.int32)
+            for i, s in active.items():
+                tokens[i] = s.last_token
+                positions[i] = s.pages.num_tokens - 1   # slot appended above
+                bt[i, :len(s.pages.pages)] = s.pages.pages
+            st = self._sampling_state(active)
+        with tracing.span("engine.decode.dispatch", rows=len(active),
+                          batch=self.max_batch):
+            self.arena.kv, nxt = self.decode_fn(
+                self.params, self.arena.kv, bt, positions, tokens, st)
         self._emit_decoded(active, nxt)
 
     # ------------------------------------------------- speculative decode
@@ -1284,84 +1325,93 @@ class ServingEngine:
             return
         k = self.speculate_k
         draft = self.draft
-        entries = []
-        for i, s in spec.items():
-            # the draft's target context: every token except the newest
-            # (s.last_token is the propose scan's first input)
-            needed = s.request.virtual_len + len(s.generated) - 1
-            reset = not 0 <= s.draft_pos <= needed
-            pos = 0 if reset else s.draft_pos
-            if reset or pos < needed:
-                ctx = np.concatenate(
-                    [np.asarray(s.request.prompt, np.int32),
-                     np.asarray(s.generated[:-1], np.int32)])
-                entries.append((i, ctx[pos:needed], reset))
-            s.draft_pos = needed
-        draft.sync(entries)
+        with tracing.span("engine.verify.build"):
+            entries = []
+            for i, s in spec.items():
+                # the draft's target context: every token except the
+                # newest (s.last_token is the propose scan's first input)
+                needed = s.request.virtual_len + len(s.generated) - 1
+                reset = not 0 <= s.draft_pos <= needed
+                pos = 0 if reset else s.draft_pos
+                if reset or pos < needed:
+                    ctx = np.concatenate(
+                        [np.asarray(s.request.prompt, np.int32),
+                         np.asarray(s.generated[:-1], np.int32)])
+                    entries.append((i, ctx[pos:needed], reset))
+                s.draft_pos = needed
+            draft.sync(entries)
 
-        last = np.zeros((self.max_batch,), np.int32)
-        for i, s in spec.items():
-            last[i] = s.last_token
-        st = self._sampling_state(spec)
-        # with a fused step (rewindable draft, single arena) the propose
-        # scan runs INSIDE the verify dispatch — the window never visits
-        # the host; otherwise draft first, verify second
-        proposed = (None if self.fused_fn is not None
-                    else draft.propose(last, st, k))
-        self.spec_stats["windows"] += len(spec)
-        self.spec_stats["draft_tokens"] += len(spec) * k
+            last = np.zeros((self.max_batch,), np.int32)
+            for i, s in spec.items():
+                last[i] = s.last_token
+            st = self._sampling_state(spec)
+            # with a fused step (rewindable draft, single arena) the
+            # propose scan runs INSIDE the verify dispatch — the window
+            # never visits the host; otherwise draft first, verify second
+            proposed = None
+            if self.fused_fn is None:
+                with tracing.span("engine.verify.propose"):
+                    proposed = draft.propose(last, st, k)
+            self.spec_stats["windows"] += len(spec)
+            self.spec_stats["draft_tokens"] += len(spec) * k
 
-        for i, s in list(spec.items()):
-            if self.slots.get(i) is not s:
-                continue                 # preempted growing an older slot
-            if s.pages.num_tokens % self.page_size:
-                # the window's first write lands in the current partial
-                # last page — COW it BEFORE appending: the appended
-                # pages are fresh, so append-then-cow (the 1-token
-                # `_grow_for_write` order) would check the wrong page.
-                # At a page boundary there is nothing to COW — every
-                # written page stays shared, every new page is private.
-                if not self._with_preemption(
-                        s, lambda s=s: self.arena.cow_for_write(s.pages)):
-                    continue             # slot yielded its pages
-            self._with_preemption(
-                s, lambda s=s: s.pages.append_tokens(k + 1))
-        live = {i: s for i, s in spec.items() if self.slots.get(i) is s}
+            for i, s in list(spec.items()):
+                if self.slots.get(i) is not s:
+                    continue             # preempted growing an older slot
+                if s.pages.num_tokens % self.page_size:
+                    # the window's first write lands in the current
+                    # partial last page — COW it BEFORE appending: the
+                    # appended pages are fresh, so append-then-cow (the
+                    # 1-token `_grow_for_write` order) would check the
+                    # wrong page.  At a page boundary there is nothing to
+                    # COW — every written page stays shared, every new
+                    # page is private.
+                    if not self._with_preemption(
+                            s, lambda s=s: self.arena.cow_for_write(
+                                s.pages)):
+                        continue         # slot yielded its pages
+                self._with_preemption(
+                    s, lambda s=s: s.pages.append_tokens(k + 1))
+            live = {i: s for i, s in spec.items() if self.slots.get(i) is s}
 
-        # rows preempted mid-window (and rows that never speculated)
-        # grow their draft context by 0 tokens: rollback restores their
-        # pre-propose checkpoint state
-        n = np.zeros((self.max_batch,), np.int32)
-        target = np.zeros((self.max_batch, k + 1), np.int32)
-        if live:
+            # rows preempted mid-window (and rows that never speculated)
+            # grow their draft context by 0 tokens: rollback restores
+            # their pre-propose checkpoint state
             b = self.max_batch
+            n = np.zeros((b,), np.int32)
+            target = np.zeros((b, k + 1), np.int32)
             start = np.zeros((b,), np.int32)
-            bt = np.full((b, self.max_pages), self.arena.null_page,
-                         np.int32)
+            bt = np.full((b, self.max_pages), self.arena.null_page, np.int32)
             for i, s in live.items():
                 start[i] = s.pages.num_tokens - (k + 1)
                 bt[i, :len(s.pages.pages)] = s.pages.pages
-            if self.fused_fn is not None:
-                mask = np.zeros((b,), bool)
-                mask[list(live)] = True
-                (self.arena.kv, draft.cache, target,
-                 accept) = self.fused_fn(
-                    self.params, draft.params, draft.cache,
-                    last, st, self.arena.kv, bt, start, mask)
-            else:
+            if live and self.fused_fn is None:
                 tokens = np.zeros((b, k + 1), np.int32)
                 clen = np.zeros((b,), np.int32)
                 for i, s in live.items():
                     tokens[i, 0] = s.last_token
                     tokens[i, 1:] = proposed[i]
                     clen[i] = k + 1
-                self.arena.kv, target, accept = self.verify_fn(
-                    self.params, {"tokens": tokens}, self.arena.kv,
-                    bt, start, clen, proposed,
-                    self._sampling_state(live))
-            target = np.asarray(target)
-            accept = np.asarray(accept)
+                live_st = self._sampling_state(live)
+        if live:
+            with tracing.span("engine.verify.dispatch", rows=len(live),
+                              batch=b):
+                if self.fused_fn is not None:
+                    mask = np.zeros((b,), bool)
+                    mask[list(live)] = True
+                    (self.arena.kv, draft.cache, target,
+                     accept) = self.fused_fn(
+                        self.params, draft.params, draft.cache,
+                        last, st, self.arena.kv, bt, start, mask)
+                else:
+                    self.arena.kv, target, accept = self.verify_fn(
+                        self.params, {"tokens": tokens}, self.arena.kv,
+                        bt, start, clen, proposed, live_st)
+            with tracing.span("engine.verify.readback"):
+                target = np.asarray(target)
+                accept = np.asarray(accept)
             self.spec_stats["verify_calls"] += 1
+        with tracing.span("engine.verify.emit"):
             for i, s in live.items():
                 sp = s.request.sampling
                 emitted = 0
@@ -1382,8 +1432,8 @@ class ServingEngine:
                 n[i] = emitted
                 self.spec_stats["accepted_tokens"] += int(accept[i])
                 self.spec_stats["emitted_tokens"] += emitted
-        if proposed is not None:
-            draft.rollback(target, n)
+            if proposed is not None:
+                draft.rollback(target, n)
         # the fused step already landed its rewind in-jit (pos grows by
         # accept+1 on live rows): a row that emitted FEWER tokens hit a
         # stop or its budget and retires this tick, so its stale draft
@@ -1393,12 +1443,15 @@ class ServingEngine:
         active = self._decode_rows()
         if not active:
             return
-        tokens = np.zeros((self.max_batch,), np.int32)
-        for i, s in active.items():
-            tokens[i] = s.last_token
-        self.cache, nxt = self.decode_fn(
-            self.params, self.cache, tokens,
-            self._sampling_state(active))
+        with tracing.span("engine.decode.build"):
+            tokens = np.zeros((self.max_batch,), np.int32)
+            for i, s in active.items():
+                tokens[i] = s.last_token
+            st = self._sampling_state(active)
+        with tracing.span("engine.decode.dispatch", rows=len(active),
+                          batch=self.max_batch):
+            self.cache, nxt = self.decode_fn(self.params, self.cache,
+                                             tokens, st)
         self._emit_decoded(active, nxt)
 
     def _finish_slot(self, i: int, s: _Slot, reason: str) -> Result:
@@ -1456,6 +1509,7 @@ class ServingEngine:
             if r.uid != uid:
                 continue
             self.pending.pop(j)
+            self._queued_at.pop(uid, None)
             if self.host_tier is not None:
                 self.host_tier.take(uid)         # drop the cold parcel
             self._prefetched.pop(uid, None)
@@ -1508,16 +1562,23 @@ class ServingEngine:
                 break
 
     def step(self):
-        self._admit()
-        self._tier_prefetch()       # overlap host->device copy with compute
-        self._prefill_tick()
-        self._enforce_high_watermark()
-        if self.layout == "paged":
-            self._decode_paged()
-        else:
-            self._decode_contiguous()
-        self.steps += 1
-        self._retire()
+        """One tick, traced as an `engine.step` span (its phases are
+        child spans; serve/tracing.py)."""
+        with tracing.span("engine.step", engine=self.trace_id,
+                          tick=self.steps):
+            with tracing.span("engine.admit"):
+                self._admit()
+            with tracing.span("engine.tier_prefetch"):
+                self._tier_prefetch()   # overlap host->device copy with compute
+            self._prefill_tick()
+            self._enforce_high_watermark()
+            if self.layout == "paged":
+                self._decode_paged()
+            else:
+                self._decode_contiguous()
+            self.steps += 1
+            with tracing.span("engine.retire"):
+                self._retire()
 
     def stream(self, max_steps: int = 10_000):
         """Tick the engine and yield TokenEvent/FinishEvent records as

@@ -87,7 +87,10 @@ def make_paged_serve_fns(cfg: ModelConfig):
         -> (arena, next_tokens)
 
     Sampling is per-slot `SamplingState` arrays evaluated in-step; the
-    (b, vocab) logits never leave the jit.
+    (b, vocab) logits never leave the jit.  The bodies run under the
+    name scopes `prefill_step` / `decode_step`; the programs keep the
+    names `jit_prefill_chunk` / `jit_decode`, by which a profiler trace
+    finds them.
     """
     fam = registry.get_family(cfg)
     if not registry.has_paged(cfg):
@@ -100,6 +103,7 @@ def make_paged_serve_fns(cfg: ModelConfig):
     cpu = jax.default_backend() == "cpu"
 
     @partial(jax.jit, donate_argnums=() if cpu else (2,))
+    @jax.named_scope("prefill_step")
     def prefill_chunk(params, chunk, arena, block_table, start, chunk_len,
                       sampling: SamplingState):
         arena, logits = fam.paged_prefill(params, cfg, chunk, arena,
@@ -107,6 +111,7 @@ def make_paged_serve_fns(cfg: ModelConfig):
         return arena, sample_tokens(logits, sampling)
 
     @partial(jax.jit, donate_argnums=() if cpu else (1,))
+    @jax.named_scope("decode_step")
     def decode(params, arena, block_table, positions, tokens,
                sampling: SamplingState):
         arena, logits = fam.paged_decode_step(params, cfg, arena,
@@ -139,6 +144,7 @@ def make_paged_verify_fn(cfg: ModelConfig):
     cpu = jax.default_backend() == "cpu"
 
     @partial(jax.jit, donate_argnums=() if cpu else (2,))
+    @jax.named_scope("verify_step")
     def verify(params, chunk, arena, block_table, start, chunk_len, draft,
                sampling: SamplingState):
         arena, logits = fam.paged_verify(params, cfg, chunk, arena,

@@ -79,6 +79,7 @@ def make_sharded_serve_fns(cfg: ModelConfig, mesh: Mesh, num_pages: int,
         out_specs=(arena_specs, rep), check_vma=False)
 
     @partial(jax.jit, donate_argnums=() if cpu else (2,))
+    @jax.named_scope("prefill_step")
     def prefill_chunk(params, chunk, arena, block_table, start, chunk_len,
                       sampling: SamplingState):
         arena, logits = prefill_sharded(params, chunk, arena, block_table,
@@ -86,6 +87,7 @@ def make_sharded_serve_fns(cfg: ModelConfig, mesh: Mesh, num_pages: int,
         return arena, sample_tokens(logits, sampling)
 
     @partial(jax.jit, donate_argnums=() if cpu else (1,))
+    @jax.named_scope("decode_step")
     def decode(params, arena, block_table, positions, tokens,
                sampling: SamplingState):
         arena, logits = decode_sharded(params, arena, block_table, positions,
@@ -124,6 +126,7 @@ def make_sharded_verify_fn(cfg: ModelConfig, mesh: Mesh, num_pages: int,
         out_specs=(arena_specs, rep), check_vma=False)
 
     @partial(jax.jit, donate_argnums=() if cpu else (2,))
+    @jax.named_scope("verify_step")
     def verify(params, chunk, arena, block_table, start, chunk_len, draft,
                sampling: SamplingState):
         arena, logits = verify_sharded(params, chunk, arena, block_table,

@@ -185,6 +185,7 @@ class DraftModel:
             cpu = jax.default_backend() == "cpu"
 
             @partial(jax.jit, donate_argnums=() if cpu else (5,))
+            @jax.named_scope("verify_step")
             def fused(tparams, dparams, cache, last, st: SamplingState,
                       arena, block_table, start, live):
                 pos0 = cache["pos"]
