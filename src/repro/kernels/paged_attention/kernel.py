@@ -38,10 +38,10 @@ Kernel geometry
   pipeline) and reduces all of them in one (g_pad, ppb*page) score
   tile.  Block tables whose width is not a ppb multiple are padded with
   a repeat of the last column; the position mask zeroes the surplus.
-* **Scalar prefetch** — the block table, positions and per-slot page
-  position bases arrive via `PrefetchScalarGridSpec`, so the K/V index
-  maps themselves walk the UniMem page table and the gather never
-  materializes a contiguous copy of the sequence.
+* **Scalar prefetch** — the block table, live-block counts, positions
+  and per-slot page position bases arrive via `PrefetchScalarGridSpec`,
+  so the K/V index maps themselves walk the UniMem page table and the
+  gather never materializes a contiguous copy of the sequence.
 * **page_positions** — each block-table slot carries the ABSOLUTE kv
   position of its page's first token ((b, max_pages) int32, default
   `arange(max_pages) * page`).  A sharded arena hands every chip a
@@ -56,10 +56,21 @@ Kernel geometry
   cross the interconnect; `combine_splits` (or a psum-style LSE merge
   over a mesh axis) folds them into the exact global softmax.
 
-Pages past a sequence's length may point at the arena's null slot; the
-position mask zeroes their contribution, and a fully masked block
-leaves the carry untouched (p is masked to 0 before it ever reaches l
-or acc).
+* **Live range** — each row walks only the page blocks some query can
+  see: `nlive` ((b,) int32, scalar-prefetched, `live_blocks`) is 1 +
+  the index of the row's last block holding any slot at or before its
+  last query position.  Cells at or past it skip their work
+  (`pl.when`) and their index maps clamp to the row's last live block,
+  so the pipeline starts no copy for them; the page-block-0 reset and
+  the last-block emit still run, so a row with no live block emits
+  exact zeros (or the empty carry in partials mode).  A row whose
+  table is live up to `max_seq` walks every block, as before.
+
+Pages past a sequence's length may point at the arena's null slot;
+blocks wholly past it are neither fetched nor computed, and inside a
+live block the position mask zeroes them.  Skipping is exact: a fully
+masked block leaves the carry untouched (p is masked to 0 before it
+ever reaches l or acc, and the correction factor is exp(0) = 1).
 """
 from __future__ import annotations
 
@@ -199,6 +210,19 @@ def emit_partials(acc_ref, m_ref, l_ref, m_scr, l_scr, acc_scr):
     l_ref[0] = l_scr[...]
 
 
+def live_blocks(ppos, last, ppb: int):
+    """(b,) int32 page blocks each row's walk computes: 1 + the index of
+    the last block of the padded (b, nb*ppb) position table `ppos` that
+    holds a slot at or before the row's last query position `last`
+    ((b,), negative for a row with no query), 0 where no block does.
+    The last such block, not a count of leading ones, so any table
+    order (a shard's compacted walk with POS_PAD holes) is exact."""
+    b, w = ppos.shape
+    live = (ppos <= last[:, None]).reshape(b, w // ppb, ppb).any(axis=-1)
+    idx = jnp.arange(1, w // ppb + 1, dtype=jnp.int32)
+    return jnp.max(jnp.where(live, idx, 0), axis=1).astype(jnp.int32)
+
+
 def block_kv_positions(ppos_ref, bi, pi, ppb: int, page: int, rows: int):
     """(rows, ppb*page) absolute kv position of every score column in a
     grid cell, from the scalar-prefetched per-slot position bases."""
@@ -207,15 +231,25 @@ def block_kv_positions(ppos_ref, bi, pi, ppb: int, page: int, rows: int):
         [ppos_ref[bi, pi * ppb + j] + within for j in range(ppb)], axis=1)
 
 
+def _walked_page(bi, pi, bt, nlive, ppb: int, j: int):
+    """Arena page of page slot j in grid cell (bi, pi).  Cells past the
+    row's live range re-use its last live block (block 0 for a row with
+    none): the index repeats, so the pipeline copies nothing for them."""
+    blk = jnp.minimum(pi, jnp.maximum(nlive[bi] - 1, 0))
+    return bt[bi, blk * ppb + j]
+
+
 def kv_block_specs(page: int, hkv: int, d: int, ppb: int):
     """One K and one V BlockSpec per page slot of a grid cell, indexed
-    through the scalar-prefetched block table (first prefetch ref).
-    Each block is a whole page, all KV heads included; the DMAs are
-    independent and pipeline across the sequential walk."""
+    through the scalar-prefetched block table and live-block counts
+    (the first two prefetch refs).  Each block is a whole page, all KV
+    heads included; the DMAs are independent and pipeline across the
+    sequential walk."""
     def spec(j):
         return pl.BlockSpec(
             (1, page, hkv, d),
-            lambda bi, pi, bt, *rest, j=j: (bt[bi, pi * ppb + j], 0, 0, 0))
+            lambda bi, pi, bt, nlive, *rest, j=j: (
+                _walked_page(bi, pi, bt, nlive, ppb, j), 0, 0, 0))
     return [spec(j) for j in range(ppb)] * 2
 
 
@@ -226,7 +260,8 @@ def scale_block_specs(page: int, hkv: int, ppb: int):
     def spec(j):
         return pl.BlockSpec(
             (1, page, hkv),
-            lambda bi, pi, bt, *rest, j=j: (bt[bi, pi * ppb + j], 0, 0))
+            lambda bi, pi, bt, nlive, *rest, j=j: (
+                _walked_page(bi, pi, bt, nlive, ppb, j), 0, 0))
     return [spec(j) for j in range(ppb)] * 2
 
 
@@ -259,7 +294,7 @@ COMPILER_PARAMS = pltpu.CompilerParams(
     dimension_semantics=("parallel", "arbitrary"))
 
 
-def _paged_kernel(bt_ref, pos_ref, ppos_ref, q_ref, *refs,
+def _paged_kernel(bt_ref, nlive_ref, pos_ref, ppos_ref, q_ref, *refs,
                   page_size: int, ppb: int, nb: int, hkv: int, d: int,
                   d_pad: int, partials: bool, nscale: int = 0):
     kv_refs = refs[:2 * ppb]
@@ -276,10 +311,13 @@ def _paged_kernel(bt_ref, pos_ref, ppos_ref, q_ref, *refs,
     def _init():
         reset_carry(m_scr, l_scr, acc_scr)
 
-    kv_pos = block_kv_positions(ppos_ref, bi, pi, ppb, page_size,
-                                q_ref.shape[2])            # (g_pad, ppb*page)
-    attend_block(q_ref, kv_refs, scale_refs, kv_pos <= pos_ref[bi],
-                 m_scr, l_scr, acc_scr, hkv=hkv, ppb=ppb, d=d, d_pad=d_pad)
+    @pl.when(pi < nlive_ref[bi])
+    def _attend():
+        kv_pos = block_kv_positions(ppos_ref, bi, pi, ppb, page_size,
+                                    q_ref.shape[2])        # (g_pad, ppb*page)
+        attend_block(q_ref, kv_refs, scale_refs, kv_pos <= pos_ref[bi],
+                     m_scr, l_scr, acc_scr, hkv=hkv, ppb=ppb, d=d,
+                     d_pad=d_pad)
 
     @pl.when(pi == nb - 1)
     def _emit():
@@ -316,6 +354,8 @@ def paged_decode_attention_pallas(q, k_pages, v_pages, block_table,
     if page_positions is None:
         page_positions = default_page_positions(block_table, page)
     bt, ppos, nb = _pad_block_table(block_table, page_positions, ppb)
+    positions = positions.astype(jnp.int32)
+    nlive = live_blocks(ppos, positions, ppb)
 
     g_pad = _round_up(max(group, SUBLANE), SUBLANE)
     d_pad = _round_up(d, LANE)
@@ -332,7 +372,7 @@ def paged_decode_attention_pallas(q, k_pages, v_pages, block_table,
 
     # index maps take the grid indices first, then the scalar-prefetch refs
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
+        num_scalar_prefetch=4,
         grid=(b, nb),
         in_specs=[pl.BlockSpec((1, hkv, g_pad, d_pad),
                                lambda bi, pi, *pref: (bi, 0, 0, 0))]
@@ -349,7 +389,7 @@ def paged_decode_attention_pallas(q, k_pages, v_pages, block_table,
         out_shape=out_shape,
         compiler_params=COMPILER_PARAMS,
         interpret=interpret,
-    )(bt, positions.astype(jnp.int32), ppos, qg,
+    )(bt, nlive, positions, ppos, qg,
       *([k_pages] * ppb), *([v_pages] * ppb), *scale_args)
     if partials:
         acc, m, l = out

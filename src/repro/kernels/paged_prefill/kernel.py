@@ -32,6 +32,10 @@ Kernel geometry
 * **pages_per_block** — as in the decode kernel: `ppb` physical pages
   per sequential cell via one scalar-prefetched BlockSpec per page
   slot; non-multiple table widths pad with the last column (masked).
+* **Live range** — as in the decode kernel, each row walks only up to
+  the block holding its last query position, start + chunk_len - 1 (a
+  row with chunk_len 0 walks none): blocks past it are neither fetched
+  nor computed, and the row still emits (zeros where it has none).
 """
 from __future__ import annotations
 
@@ -46,11 +50,11 @@ from repro.kernels.paged_attention.kernel import (
     COMPILER_PARAMS, LANE, SUBLANE, _pad_block_table, _round_up,
     attend_block, block_kv_positions, carry_outputs, carry_scratch,
     default_page_positions, emit_output, emit_partials, kv_block_specs,
-    reset_carry, scale_block_specs)
+    live_blocks, reset_carry, scale_block_specs)
 
 
-def _prefill_kernel(bt_ref, start_ref, clen_ref, ppos_ref, q_ref, *refs,
-                    page_size: int, ppb: int, nb: int, hkv: int,
+def _prefill_kernel(bt_ref, nlive_ref, start_ref, clen_ref, ppos_ref, q_ref,
+                    *refs, page_size: int, ppb: int, nb: int, hkv: int,
                     group: int, d: int, d_pad: int, partials: bool,
                     nscale: int = 0):
     kv_refs = refs[:2 * ppb]
@@ -71,13 +75,15 @@ def _prefill_kernel(bt_ref, start_ref, clen_ref, ppos_ref, q_ref, *refs,
     # causal over absolute positions AND ragged chunk_len row validity
     # (tail rows and the sublane-padding rows past c*group get
     # ci >= chunk_len and end up exact zeros via the masked carry)
-    kv_pos = block_kv_positions(ppos_ref, bi, pi, ppb, page_size,
-                                q_ref.shape[2])            # (R, ppb*page)
-    ci = jax.lax.broadcasted_iota(jnp.int32, kv_pos.shape, 0) // group
-    q_pos = start_ref[bi] + ci                             # absolute position
-    valid = (kv_pos <= q_pos) & (ci < clen_ref[bi])
-    attend_block(q_ref, kv_refs, scale_refs, valid, m_scr, l_scr, acc_scr,
-                 hkv=hkv, ppb=ppb, d=d, d_pad=d_pad)
+    @pl.when(pi < nlive_ref[bi])
+    def _attend():
+        kv_pos = block_kv_positions(ppos_ref, bi, pi, ppb, page_size,
+                                    q_ref.shape[2])        # (R, ppb*page)
+        ci = jax.lax.broadcasted_iota(jnp.int32, kv_pos.shape, 0) // group
+        q_pos = start_ref[bi] + ci                         # absolute position
+        valid = (kv_pos <= q_pos) & (ci < clen_ref[bi])
+        attend_block(q_ref, kv_refs, scale_refs, valid, m_scr, l_scr,
+                     acc_scr, hkv=hkv, ppb=ppb, d=d, d_pad=d_pad)
 
     @pl.when(pi == nb - 1)
     def _emit():
@@ -114,6 +120,10 @@ def paged_prefill_attention_pallas(q, k_pages, v_pages, block_table, start,
     if page_positions is None:
         page_positions = default_page_positions(block_table, page)
     bt, ppos, nb = _pad_block_table(block_table, page_positions, ppb)
+    start = start.astype(jnp.int32)
+    chunk_len = chunk_len.astype(jnp.int32)
+    nlive = live_blocks(ppos, jnp.where(chunk_len > 0,
+                                        start + chunk_len - 1, -1), ppb)
 
     d_pad = _round_up(d, LANE)
     qg = jnp.moveaxis(q.reshape(b, c, hkv, group, d), 2, 1)
@@ -133,7 +143,7 @@ def paged_prefill_attention_pallas(q, k_pages, v_pages, block_table, start,
     scale_args = ((*([k_scale] * ppb), *([v_scale] * ppb)) if quant else ())
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=4,
+        num_scalar_prefetch=5,
         grid=(b, nb),
         in_specs=[pl.BlockSpec((1, hkv, R, d_pad),
                                lambda bi, pi, *pref: (bi, 0, 0, 0))]
@@ -150,7 +160,7 @@ def paged_prefill_attention_pallas(q, k_pages, v_pages, block_table, start,
         out_shape=out_shape,
         compiler_params=COMPILER_PARAMS,
         interpret=interpret,
-    )(bt, start.astype(jnp.int32), chunk_len.astype(jnp.int32), ppos, qg,
+    )(bt, nlive, start, chunk_len, ppos, qg,
       *([k_pages] * ppb), *([v_pages] * ppb), *scale_args)
 
     def unpack(x, dd):

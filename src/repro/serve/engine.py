@@ -908,7 +908,9 @@ class ServingEngine:
             return
         pre, chunk, bt, start, clen, st = batch
         b, c = chunk["tokens"].shape
-        with tracing.span("engine.prefill.dispatch", rows=len(pre), batch=b):
+        walk = self._walk_counts(np.where(clen > 0, start + clen - 1, -1))
+        with tracing.span("engine.prefill.dispatch", rows=len(pre), batch=b,
+                          **walk):
             self.arena.kv, first = self.prefill_fn(
                 self.params, chunk, self.arena.kv, bt, start, clen, st)
         self.prefill_shapes.add((b, c))
@@ -922,6 +924,18 @@ class ServingEngine:
                 if not s.prefilling:             # prompt complete: the
                                                  # step sampled token 0
                     self._emit(s, self._next_token(s, int(first[i])))
+
+    def _walk_counts(self, last) -> dict:
+        """The `blocks` and `slots` counts of a paged dispatch span: the
+        page blocks the fused kernels compute, each row up to the block
+        holding its last query position (`last`, negative for a row
+        with none; `kernels/paged_attention.live_blocks`), and the
+        blocks a walk of every row's whole table would.  On a `mem`
+        mesh both count the unsharded walk."""
+        ppb = max(1, min(self.cfg.attn_pages_per_block, self.max_pages))
+        blocks = np.where(last >= 0, last // (self.page_size * ppb) + 1, 0)
+        return dict(blocks=int(blocks.sum()),
+                    slots=len(last) * -(-self.max_pages // ppb))
 
     def _prefill_batch(self):
         """This tick's prefill rows and the step's arguments: (rows,
@@ -1242,7 +1256,8 @@ class ServingEngine:
                 bt[i, :len(s.pages.pages)] = s.pages.pages
             st = self._sampling_state(active)
         with tracing.span("engine.decode.dispatch", rows=len(active),
-                          batch=self.max_batch):
+                          batch=self.max_batch,
+                          **self._walk_counts(positions)):
             self.arena.kv, nxt = self.decode_fn(
                 self.params, self.arena.kv, bt, positions, tokens, st)
         self._emit_decoded(active, nxt)
@@ -1381,9 +1396,11 @@ class ServingEngine:
             n = np.zeros((b,), np.int32)
             target = np.zeros((b, k + 1), np.int32)
             start = np.zeros((b,), np.int32)
+            newest = np.full((b,), -1, np.int32)  # inert rows walk nothing
             bt = np.full((b, self.max_pages), self.arena.null_page, np.int32)
             for i, s in live.items():
                 start[i] = s.pages.num_tokens - (k + 1)
+                newest[i] = start[i] + k
                 bt[i, :len(s.pages.pages)] = s.pages.pages
             if live and self.fused_fn is None:
                 tokens = np.zeros((b, k + 1), np.int32)
@@ -1395,7 +1412,7 @@ class ServingEngine:
                 live_st = self._sampling_state(live)
         if live:
             with tracing.span("engine.verify.dispatch", rows=len(live),
-                              batch=b):
+                              batch=b, **self._walk_counts(newest)):
                 if self.fused_fn is not None:
                     mask = np.zeros((b,), bool)
                     mask[list(live)] = True

@@ -179,6 +179,98 @@ def test_fused_prefill_kernel_geometries(hq, hkv, hd, page, mp, ppb):
     assert np.all(np.asarray(got[1, int(clen[1]):]) == 0.0)  # partial tail
 
 
+# Dead blocks: every page of a block wholly past its row's live range is
+# filled with NaN (pages past the range inside a live block stay clean,
+# the mask keeps them out but 0 * NaN would not).  A kernel that fetched
+# and computed such a block would carry NaN into p @ v; the guarded walk
+# must return what it returns over a clean tail, bit for bit.  Cases:
+# every geometry at ppb 1 and at its own ppb (one that does not divide
+# the table among them), then a shard's compacted table in partials mode
+# and an int8 arena whose scale banks carry the NaN.
+
+GUARD_CASES = ([(g, ppb, "plain") for g in GEOMETRIES for ppb in (1, g[-1])]
+               + [(GEOMETRIES[2], 2, "partials"),
+                  (GEOMETRIES[2], 2, "int8")])
+
+
+def _live_blocks_by_hand(ppos, last, ppb):
+    """Per row, 1 + the last block (of ppb table slots) holding a slot
+    at or before the row's last query position; 0 where none does."""
+    b, mp = ppos.shape
+    out = []
+    for i in range(b):
+        live = [j for j in range(-(-mp // ppb))
+                if (ppos[i, j * ppb:(j + 1) * ppb] <= last[i]).any()]
+        out.append(max(live) + 1 if live else 0)
+    return out
+
+
+@pytest.mark.parametrize("kernel", ["decode", "prefill"])
+@pytest.mark.parametrize("geom,ppb,variant", GUARD_CASES)
+def test_dead_page_blocks_are_neither_fetched_nor_computed(kernel, geom,
+                                                           ppb, variant):
+    from repro.core.unimem import quantize_kv
+    from repro.kernels.paged_attention.kernel import POS_PAD
+    hq, hkv, hd, page, mp = geom[:5]
+    rng = np.random.default_rng(hq * 1000 + hd + ppb)
+    b, c = 3, 8
+    k, v, bt = _geom_setup(rng, b, hd, page, mp, hkv)
+    # slot j's first position: logical page j, or with "partials" the
+    # odd logical pages shard 1 of 2 holds (so twice the span)
+    lp = np.arange(mp) * page
+    if variant == "partials":
+        lp = (2 * np.arange(mp) + 1) * page
+    span = int(lp[-1]) + page
+    # rows: one whose first query is early, one live to its table's end,
+    # one in between; the prefill's first row has chunk_len 0
+    mid = int(rng.integers(0, span - c))
+    if kernel == "decode":
+        rows = (jnp.asarray([1, span - 1, mid], jnp.int32),)
+        last = np.asarray(rows[0])
+    else:
+        clen = np.asarray([0, c, int(rng.integers(1, c))], np.int32)
+        start = np.asarray([mid, span - c, mid], np.int32)
+        rows = (jnp.asarray(start), jnp.asarray(clen))
+        last = np.where(clen > 0, start + clen - 1, -1)
+    ppos = np.broadcast_to(lp, (b, mp)).astype(np.int32)
+    kw = {}
+    if variant == "partials":
+        # a compacted walk: POS_PAD for the pages not yet written and
+        # for a hole another shard owns
+        ppos = np.where(ppos <= last[:, None], ppos, POS_PAD)
+        ppos[:, 1] = POS_PAD
+        kw = dict(page_positions=jnp.asarray(ppos), partials=True)
+    dead = [int(bt[i, j]) for i, n in
+            enumerate(_live_blocks_by_hand(ppos, last, ppb))
+            for j in range(n * ppb, mp)]
+    # a decode row always sees its first block: one block has none dead
+    assert (dead or kernel == "decode" and ppb >= mp) and len(dead) < b * mp
+    nan = lambda x: x.at[jnp.asarray(dead, jnp.int32)].set(jnp.nan)
+    if variant == "int8":
+        k, ks = quantize_kv(k, jnp.int8)
+        v, vs = quantize_kv(v, jnp.int8)
+        arenas = [(k, v, dict(kw, k_scale=ks, v_scale=vs)),
+                  (k, v, dict(kw, k_scale=nan(ks), v_scale=nan(vs)))]
+    else:
+        arenas = [(k, v, kw), (nan(k), nan(v), kw)]
+    if kernel == "decode":
+        qshape, run, ref = (b, hq, hd), paged_decode_attention, \
+            paged_decode_attention_ref
+    else:
+        qshape, run, ref = (b, c, hq, hd), paged_prefill_attention, \
+            paged_prefill_attention_ref
+    q = jnp.asarray(rng.standard_normal(qshape), jnp.float32)
+    want, got = [run(q, kk, vv, bt, *rows, pages_per_block=ppb,
+                     interpret=True, **a) for kk, vv, a in arenas]
+    for w, g in zip(jax.tree.leaves(want), jax.tree.leaves(got)):
+        assert np.all(np.isfinite(np.asarray(g)))
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+    for w, o in zip(jax.tree.leaves(want),
+                    jax.tree.leaves(ref(q, k, v, bt, *rows, **arenas[0][2]))):
+        np.testing.assert_allclose(np.asarray(w), np.asarray(o),
+                                   rtol=1e-5, atol=1e-5)
+
+
 def test_fused_prefill_matches_dense_attention_oracle():
     """A chunk at offset `start` into a contiguously-mapped single
     sequence equals dense causal attention with a query offset — the

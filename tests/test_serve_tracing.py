@@ -102,6 +102,69 @@ def test_dispatch_spans_serve_what_the_recorder_records(params):
     assert max(a["rows"] for a in pre) > 1 and max(a["rows"] for a in dec) > 1
 
 
+@pytest.mark.parametrize("ppb", [1, 3])
+def test_dispatch_spans_count_the_page_blocks_the_walk_computes(params,
+                                                                  ppb):
+    """`blocks` is the page blocks the kernels compute, each row up to
+    the block holding its last query position, counted here by hand from
+    each call's own start/chunk_len and positions; `slots` is every
+    row's whole table (8 pages: 8 blocks of 1, or 3 blocks of 3)."""
+    eng = ServingEngine(TINY["dense"].replace(attn_pages_per_block=ppb),
+                        params, max_batch=4, max_seq=64, page_size=8,
+                        prefill_chunk=16)
+    last = {"prefill": [], "decode": []}
+    pf, df = eng.prefill_fn, eng.decode_fn
+
+    def prefill(params, chunk, arena, bt, start, clen, st):
+        last["prefill"].append([s + n - 1 if n else -1
+                                for s, n in zip(start.tolist(),
+                                                clen.tolist())])
+        return pf(params, chunk, arena, bt, start, clen, st)
+
+    def decode(params, arena, bt, positions, tokens, st):
+        last["decode"].append(positions.tolist())  # inert rows: 0
+        return df(params, arena, bt, positions, tokens, st)
+
+    eng.prefill_fn, eng.decode_fn = prefill, decode
+    _submit(eng, 4, plen=37, max_new=5)
+    eng.run()
+    nb = -(-8 // ppb)
+
+    def by_hand(lasts):
+        return sum(j * ppb * 8 <= p for p in lasts for j in range(nb))
+
+    spans = sorted(_mine(eng), key=lambda s: s.start_ns)
+    for kind in ("prefill", "decode"):
+        got = [(s.attrs["blocks"], s.attrs["slots"]) for s in spans
+               if s.name == f"engine.{kind}.dispatch"]
+        assert got == [(by_hand(p), 4 * nb) for p in last[kind]]
+        assert any(0 < b < n for b, n in got)      # the walk is cut short
+
+
+def test_verify_dispatch_spans_count_each_window_to_its_last_candidate(
+        params):
+    """A speculative window's verify walk: each live row up to the block
+    holding its last candidate, start + k; the other rows walk none."""
+    k = 2
+    eng = _engine(params, speculate_k=k, draft="self:1")
+    ends = []
+    fused = eng.fused_fn
+
+    def spy(p, dp, cache, last, st, arena, bt, start, mask):
+        ends.append([s + k if m else -1
+                     for s, m in zip(start.tolist(), mask.tolist())])
+        return fused(p, dp, cache, last, st, arena, bt, start, mask)
+
+    eng.fused_fn = spy
+    _submit(eng, 3, plen=20, max_new=10)
+    eng.run()
+    got = [(s.attrs["blocks"], s.attrs["slots"])
+           for s in sorted(_mine(eng), key=lambda s: s.start_ns)
+           if s.name == "engine.verify.dispatch"]
+    assert got and got == [(sum(e // 8 + 1 for e in row if e >= 0), 4 * 8)
+                           for row in ends]
+
+
 def test_contiguous_layout_dispatches_count_their_rows():
     cfg = TINY["ssm"]
     eng = ServingEngine(cfg, registry.get_family(cfg).init(jax.random.key(0),

@@ -3,8 +3,9 @@
 The TPU compiler is installed beside JAX, and it compiles for a chip
 that is described rather than attached.  These tests lower the decode
 and chunk-prefill kernels at internlm2-1.8b serving widths (16 query
-heads over 8 KV heads, head_dim 128, page 16, a 2048-token block table)
-and compile them for one v5e chip: Mosaic's tiling rules, VMEM limits
+heads over 8 KV heads, head_dim 128, page 16, a 2048-token block table),
+and the prefill kernel at each benchmark cell's own geometry, and
+compile them for one v5e chip: Mosaic's tiling rules, VMEM limits
 and scalar-prefetch index maps are checked here, which interpret mode
 never does.  Nothing runs, so results are the interpret-mode tests'
 business.
@@ -68,13 +69,21 @@ def no_persistent_cache():
     jax.config.update("jax_enable_compilation_cache", prev)
 
 
-def _arena_args(sharding, kv_dtype):
+# the benchmark cells' prefill geometries (benchmarks/chip/cells/):
+# (batch, query heads, KV heads, block-table width, arena pages + null)
+CELL_GEOMETRIES = {
+    "internlm2-1.8b.chat": (32, 16, 8, 2560 // PAGE, 3584 + 1),
+    "yi-9b-l24.docqa": (16, 32, 4, 3648 // PAGE, 3648 + 1),
+}
+
+
+def _arena_args(sharding, kv_dtype, hkv=HKV, num_pages=NUM_PAGES):
     """Shapes of one layer's K/V arena (+ scale banks when quantized)."""
     S = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=sharding)
-    kv = S((NUM_PAGES, PAGE, HKV, HD), kv_dtype)
+    kv = S((num_pages, PAGE, hkv, HD), kv_dtype)
     scales = ()
     if kv_dtype != jnp.bfloat16:
-        scales = (S((NUM_PAGES, PAGE, HKV), jnp.float32),) * 2
+        scales = (S((num_pages, PAGE, hkv), jnp.float32),) * 2
     return S, (kv, kv, *scales)
 
 
@@ -103,14 +112,18 @@ def test_paged_decode_compiles_for_v5e(one_chip, no_persistent_cache,
     assert "tpu_custom_call" in compiled.as_text()
 
 
-@pytest.mark.parametrize("kv_dtype,chunk,ppb", [
-    (jnp.bfloat16, 64, 1),
-    (jnp.bfloat16, 256, 4),
-    (jnp.int8, 128, 2),
+@pytest.mark.parametrize("kv_dtype,chunk,ppb,cell", [
+    (jnp.bfloat16, 64, 1, None),
+    (jnp.bfloat16, 256, 4, None),
+    (jnp.int8, 128, 2, None),
+    (jnp.bfloat16, 64, 1, "internlm2-1.8b.chat"),
+    (jnp.bfloat16, 64, 1, "yi-9b-l24.docqa"),
 ])
 def test_paged_prefill_compiles_for_v5e(one_chip, no_persistent_cache,
-                                        kv_dtype, chunk, ppb):
-    S, arena = _arena_args(one_chip, kv_dtype)
+                                        kv_dtype, chunk, ppb, cell):
+    b, hq, hkv, mp, pages = CELL_GEOMETRIES.get(
+        cell, (B, HQ, HKV, MP, NUM_PAGES))
+    S, arena = _arena_args(one_chip, kv_dtype, hkv, pages)
 
     def step(q, bt, start, clen, k, v, *sc):
         return paged_prefill_attention(q, k, v, bt, start, clen,
@@ -118,8 +131,8 @@ def test_paged_prefill_compiles_for_v5e(one_chip, no_persistent_cache,
                                        **_scales(sc))
 
     compiled = jax.jit(step).lower(
-        S((B, chunk, HQ, HD), jnp.bfloat16), S((B, MP), jnp.int32),
-        S((B,), jnp.int32), S((B,), jnp.int32), *arena).compile()
+        S((b, chunk, hq, HD), jnp.bfloat16), S((b, mp), jnp.int32),
+        S((b,), jnp.int32), S((b,), jnp.int32), *arena).compile()
     assert "tpu_custom_call" in compiled.as_text()
 
 
