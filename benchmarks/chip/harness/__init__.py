@@ -1,3 +1,5 @@
 """The on-chip serving benchmark's own code: spec loading, traffic
-helpers, weights, the serving window, the float32 reference, trace
-reduction and the operation and byte counts."""
+helpers, what every block shares (the seed's key, the layout check, the
+reference's float32 and fp8 matmuls, counting arithmetic), the serving
+window and trace reduction. What belongs to one architecture is in
+`blocks/<name>.py`."""

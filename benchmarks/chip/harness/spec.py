@@ -3,7 +3,12 @@
 `BENCHMARK.json` (at the root of the checkout) names each cell's
 configuration and traffic mix; the parts live in files of their own:
 
-    configs/<config>.json     the model's sizes and where they come from
+    configs/<config>.json     the model's sizes and where they come from;
+                              its "block" names
+    blocks/<block>.py         the architecture: the configuration mapped
+                              onto the program's, the weights drawn from
+                              the seed, the float32 reference and its fp8
+                              control, the operation and byte counts
     traffic/<mix>.json        the mix's parameters; its "kind" names
     traffic/gen_<kind>.py     the generator that reads them
     cells/<workload>.json     what belongs to the pair: the engine's
@@ -12,9 +17,10 @@ configuration and traffic mix; the parts live in files of their own:
     peaks.json                the chips' peaks, keyed by device_kind
     patterns.json             trace names of the programs and kernels
 
-Nothing here imports JAX or the program."""
+Nothing here imports JAX or the program; a block does, once loaded."""
 from __future__ import annotations
 
+import functools
 import importlib.util
 import json
 from pathlib import Path
@@ -46,6 +52,14 @@ def generator(kind: str):
                         f"gen_{kind}")
 
 
+@functools.cache
+def block(name: str):
+    """The block module `blocks/<name>.py`, loaded once a process (its
+    jitted reference keeps its compiled programs)."""
+    return _load_module(BENCH_DIR / "blocks" / f"{name}.py",
+                        f"block_{name.replace('.', '_').replace('-', '_')}")
+
+
 def metric_reader(name: str):
     return _load_module(BENCH_DIR / "metrics" / f"{name}.py",
                         f"metric_{name.replace('.', '_').replace('-', '_')}")
@@ -66,11 +80,14 @@ def resolve(workload: str, bench: dict | None = None) -> dict:
     configs = {c["name"]: c for c in bench["configs"]}
     cfg_entry = configs[cell["config"]]
     config = load_json(ROOT / cfg_entry["file"])
+    if "block" not in config:
+        raise KeyError(f"{cfg_entry['file']} names no block")
     traffic = load_json(BENCH_DIR / "traffic" / f"{cell['traffic']}.json")
     own = load_json(BENCH_DIR / "cells" / f"{workload}.json")
     return dict(
         cell=cell, config=config, traffic=traffic,
         engine=own["engine"], check=own["check"],
+        block=block(config["block"]),
         generator=generator(traffic["kind"]),
         end_to_end=[m for m in bench["end_to_end"] if _applies(m, workload)],
         per_layer=[m for m in bench["per_layer"] if _applies(m, workload)],

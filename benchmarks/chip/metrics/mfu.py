@@ -1,8 +1,6 @@
 """Model step (models/transformer.py): model FLOPs of the tokens the
 traced window processed (prefill and decode, attention at each token's
 context), over window seconds x chips x the bf16 peak, in %."""
-from harness.counts import model_flops
-
 
 def read(ctx):
     rec = ctx["record"]
@@ -10,6 +8,6 @@ def read(ctx):
     pos = [p for call in rec["decode"] for p in call]
     if not rows and not pos:
         return None
-    f = model_flops(ctx["D"], rows, pos)
+    f = ctx["block"].model_flops(ctx["D"], rows, pos)
     return 100.0 * f / (ctx["window_s"] * ctx["chips"]
                         * ctx["peaks"]["bf16_flops_per_s"])

@@ -9,9 +9,9 @@ seed, warms up the cell's own programs, serves the traffic through
 `ServingEngine` for `--seconds` of host clock (then waits, at most a
 minute, for the first token of every request due in the window), and
 checks a sample of what it served, finished or in flight, against the
-float32 reference. With `--trace 1` it also records
-a profiler trace of the window and reports the per-layer metrics
-instead of the end-to-end ones. The last line of standard output is
+float32 reference of the configuration's block. With `--trace 1` it
+also records a profiler trace of the window and reports the per-layer
+metrics instead of the end-to-end ones. The last line of standard output is
 the result; the last lines of standard error are the numbers compared,
 each beside its limit. Without an accelerator it exits non-zero and
 prints no result."""
@@ -105,15 +105,14 @@ def check_served(params, D, data, parts, seed, *, control=False):
     float32 reference; returns (ok, checks, per-request readings). With
     `control`, the fp8 control's readings at the same positions go
     through the same `judge`: `control_correct` has to come out false."""
-    from harness.reference import served_gaps
     eng, chk = parts["engine"], parts["check"]
     sample = pick_sample(data, chk["sample"], seed)
     bad = token_count_errors(data, D["V"])
     pairs = [(data["requests"][u]["prompt"], data["tokens"][u])
              for u in sample]
-    gaps = served_gaps(params, D, pairs, max_seq=eng["max_seq"],
-                       max_new=parts["traffic"]["output"]["max"],
-                       control=control)
+    gaps = parts["block"].served_gaps(
+        params, D, pairs, max_seq=eng["max_seq"],
+        max_new=parts["traffic"]["output"]["max"], control=control)
     served = [float(g.max()) for g, _ in gaps]
     ok, checks = judge(served, bad, chk["gap_limit"])
     readings = dict(sample=sample, served=served,
@@ -156,7 +155,7 @@ def serve_cell(parts: dict, seed: int, seconds: float, devs, *,
     """One run of a cell on `devs`; returns everything the result line
     and the checks need. `fault`, if given, is called with the engine
     before the window (the tests plant faults through it)."""
-    from harness import model as M
+    from harness.model import check_layout
     from harness.serve import (CompileCounter, Recorder, build_engine,
                                drive, warm_up)
     from harness.trace import Capture
@@ -170,11 +169,12 @@ def serve_cell(parts: dict, seed: int, seconds: float, devs, *,
         from repro.launch.mesh import MEM_AXIS
         mesh = Mesh(devs, (MEM_AXIS,))
         sharding = NamedSharding(mesh, PartitionSpec())
-    cfg = M.model_config(config, eng)
-    D = M.dims(config)
+    B = parts["block"]
+    cfg = B.model_config(config, eng)
+    D = B.dims(config)
     t = time.perf_counter()
-    params = M.make_weights(config, seed, sharding=sharding)
-    M.check_layout(params, cfg)
+    params = B.make_weights(config, seed, sharding=sharding)
+    check_layout(params, cfg)
     log(f"weights drawn in {time.perf_counter() - t:.1f}s")
     engine = build_engine(cfg, params, eng, mesh)
     t = time.perf_counter()
@@ -250,7 +250,7 @@ def result_line(parts: dict, run: dict, devs, trace: bool) -> dict:
         ctx = dict(trace=tr, patterns=pat, record=run["record"],
                    window_s=data["close"] - data["t0"], ticks=data["ticks"],
                    D=run["D"], peaks=peaks, chips=run["chips"],
-                   kv_bytes=2)
+                   block=parts["block"], kv_bytes=parts["block"].KV_BYTES)
         res["metrics"] = per_layer(ctx, parts["per_layer"])
         device["busy_s"] = busy
         device["window_s"] = window

@@ -36,13 +36,13 @@ def main() -> int:
     parts = S.resolve(args.workload)
     import jax
     from repro.utils.compile_cache import use_compile_cache
-    from harness import model as M
     from harness.serve import build_engine, drive, warm_up
     use_compile_cache()
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
     devs = R.device_info(parts["cell"]["chips"])
-    cfg = M.model_config(parts["config"], parts["engine"])
-    D = M.dims(parts["config"])
+    B = parts["block"]
+    cfg = B.model_config(parts["config"], parts["engine"])
+    D = B.dims(parts["config"])
     mesh = sharding = None
     if len(devs) > 1:
         from jax.sharding import Mesh, NamedSharding, PartitionSpec
@@ -53,7 +53,7 @@ def main() -> int:
     program, control, control_correct = [], [], []
     for seed in args.seeds:
         t = time.perf_counter()
-        params = M.make_weights(parts["config"], seed, sharding=sharding)
+        params = B.make_weights(parts["config"], seed, sharding=sharding)
         engine = build_engine(cfg, params, parts["engine"], mesh)
         if fns is None:
             warm_up(engine)

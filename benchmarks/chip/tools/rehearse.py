@@ -45,7 +45,6 @@ def main() -> int:
     import numpy as np
     from jax.experimental import topologies
     from jax.sharding import SingleDeviceSharding
-    from harness import model as M, reference as Ref
     from repro.models import registry
     from repro.serve import serve_step
     from repro.serve.sampling import greedy_state
@@ -54,7 +53,8 @@ def main() -> int:
     eng = dict(parts["engine"])
     if args.pool_pages:
         eng["pool_pages"] = args.pool_pages
-    cfg = M.model_config(parts["config"], eng)
+    B = parts["block"]
+    cfg = B.model_config(parts["config"], eng)
     fam = registry.get_family(cfg)
     topo = topologies.get_topology_desc(platform="tpu",
                                         topology_name="v5e:2x2")
@@ -105,16 +105,14 @@ def main() -> int:
     finally:
         jax.default_backend = real
     if not args.skip_reference and chips == 1:
-        D = M.dims(parts["config"])
-        T = Ref.padded_length(eng["max_seq"], min(512, eng["max_seq"]))
         K = parts["traffic"]["output"]["max"]
-        f = Ref._gaps.lower(params, i32(T), i32(K), i32(K),
-                            jax.ShapeDtypeStruct((K,), jnp.bool_, sharding=one),
-                            D=tuple(sorted(D.items())), block=512,
-                            control=False)
-        show(f"reference (T={T}, K={K})", f.compile())
+        f = B.lower_reference(params, B.dims(parts["config"]),
+                              max_seq=eng["max_seq"], max_new=K, sharding=one)
+        show(f"reference (max_seq {eng['max_seq']}, K={K})", f.compile())
+    tok = B.kv_token_bytes(B.dims(parts["config"]))
     print(f"pool pages {pool}: arena "
-          f"{sum(np.prod(a.shape) * a.dtype.itemsize for a in jax.tree.leaves(arena)) / GIB:.3f} GiB")
+          f"{sum(np.prod(a.shape) * a.dtype.itemsize for a in jax.tree.leaves(arena)) / GIB:.3f} GiB; "
+          f"{tok} B of KV a token, {pool * eng['page_size']} tokens")
     return 0
 
 

@@ -35,15 +35,15 @@ def main() -> int:
     import jax
     import numpy as np
     from repro.utils.compile_cache import use_compile_cache
-    from harness import model as M
     from harness.latency import percentile, ttft_samples, window_rate
     from harness.serve import build_engine, drive, warm_up
     use_compile_cache()
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
     devs = R.device_info(parts["cell"]["chips"])
-    cfg = M.model_config(parts["config"], parts["engine"])
-    D = M.dims(parts["config"])
-    params = M.make_weights(parts["config"], args.seed)
+    B = parts["block"]
+    cfg = B.model_config(parts["config"], parts["engine"])
+    D = B.dims(parts["config"])
+    params = B.make_weights(parts["config"], args.seed)
     fns = None
     for rate in args.rates:
         engine = build_engine(cfg, params, parts["engine"])

@@ -52,6 +52,7 @@ def tiny_parts(widths=None, traffic=None, config="internlm2-1.8b",
     own = dict(TINY_CELL, **(cell or {}))
     return dict(cell={"name": "tiny", "chips": 1}, config=cfg, traffic=tr,
                 engine=own["engine"], check=own["check"],
+                block=S.block(cfg["block"]),
                 generator=S.generator(tr["kind"]),
                 end_to_end=bench["end_to_end"], per_layer=bench["per_layer"],
                 peaks=S.load_json(BENCH / "peaks.json"),

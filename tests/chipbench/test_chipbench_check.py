@@ -92,13 +92,13 @@ def test_fault_in_timed_path_is_not_correct(run_mod, fault):
 def test_fp8_control_reads_further_than_the_program(run_mod):
     """The control goes through the same comparison as the program and
     comes out not correct at the cell's limit."""
-    from harness import model as M
     parts = _parts()
+    B = parts["block"]
     failed = 0
     for seed in SEEDS:
         run = run_mod.serve_cell(parts, seed, 1.5, jax.devices()[:1])
-        params = M.make_weights(parts["config"], seed)
-        ok, _, rd = run_mod.check_served(params, M.dims(parts["config"]),
+        params = B.make_weights(parts["config"], seed)
+        ok, _, rd = run_mod.check_served(params, B.dims(parts["config"]),
                                          run["data"], parts, seed,
                                          control=True)
         assert ok and max(rd["control"]) >= max(rd["served"])

@@ -1,12 +1,14 @@
-"""Model FLOPs and kernel operation and byte counts against values
-worked out by hand for a tiny configuration."""
+"""The Llama block's model FLOPs and kernel operation and byte counts
+against values worked out by hand for a tiny configuration."""
 from __future__ import annotations
 
 import chipbench_common  # noqa: F401  (puts the harness on the path)
 
 import pytest
 
-from harness import counts as C
+from harness import spec as S
+
+C = S.block("llama")
 
 # L=2, d=8, ff=16, hq=4, hkv=2, hd=2, V=10
 D = dict(L=2, d=8, ff=16, hq=4, hkv=2, hd=2, V=10, theta=1e4, eps=1e-5)
@@ -49,9 +51,8 @@ def test_counts_ignore_padding_and_inert_rows():
 @pytest.mark.parametrize("name", ["internlm2-1.8b", "yi-9b-l24"])
 def test_published_parameter_counts(name):
     import json
-    from harness import model as M, spec as S
     cfg = json.loads((S.BENCH_DIR / "configs" / f"{name}.json").read_text())
-    d = M.dims(cfg)
+    d = S.block(cfg["block"]).dims(cfg)
     total = C.matmul_params(d) + d["V"] * d["d"]          # plus embedding
     want = {"internlm2-1.8b": 1.89e9, "yi-9b-l24": 4.68e9}[name]
     assert total == pytest.approx(want, rel=0.01)

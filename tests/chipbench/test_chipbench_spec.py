@@ -39,6 +39,7 @@ def test_names_units_and_keys():
         assert NAME.match(c["name"]) and c["file"].startswith("benchmarks/chip/")
         cfg = json.loads((ROOT / c["file"]).read_text())
         assert cfg["source"] == c["source"] and cfg["reduced"] == c["reduced"]
+        assert (S.BENCH_DIR / "blocks" / f"{cfg['block']}.py").is_file()
         names.add(c["name"])
     cells = set()
     for w in B["workloads"]:
